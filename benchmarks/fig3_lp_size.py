@@ -45,10 +45,8 @@ def run(full: bool = False):
             lp = shuffle_batch(jax.random.key(1), normalize_batch(
                 random_feasible_lp(jax.random.key(B + m), B, m)))
             for method in ("naive", "rgb", "kernel"):
-                solver = SolverSpec(
-                    backend=method, normalize=False,
-                    interpret=True if method == "kernel" else None,
-                ).build()
+                solver = SolverSpec(backend=method,
+                                    normalize=False).build()
                 dt = time_fn(solver.solve, lp)
                 rows.append(emit(f"fig3/b{B}/m{m}/{method}", dt,
                                  f"per_lp_us={dt/B*1e6:.2f}"))

@@ -36,8 +36,7 @@ from repro.solver import SolverSpec
 def _specs(smoke: bool):
     specs = [("rgb", SolverSpec(backend="rgb"))]
     if smoke:
-        specs.append(("kernel", SolverSpec(backend="kernel",
-                                           interpret=True)))
+        specs.append(("kernel", SolverSpec(backend="kernel")))
     return specs
 
 

@@ -25,8 +25,7 @@ def sweep_specs(full: bool = False):
         ("rgb", SolverSpec(backend="rgb", shuffle=True)),
         ("rgb-t8-c64", SolverSpec(backend="rgb", tile=8, chunk=64,
                                   shuffle=True)),
-        ("kernel", SolverSpec(backend="kernel", interpret=True,
-                              shuffle=True)),
+        ("kernel", SolverSpec(backend="kernel", shuffle=True)),
     ]
     if full:
         specs += [

@@ -24,8 +24,12 @@ TPU-native realisation of the paper's cooperative-thread-array design
   "tiles only pay for their own largest problem".
 
 All per-problem scalars are kept as (T, 1) so every intermediate is >= 2-D
-(Mosaic requires >= 2-D iota / layouts).  The kernel is validated in
-``interpret=True`` mode on CPU against ``kernels.ref`` and scipy.
+(Mosaic requires >= 2-D iota / layouts).  Constraints are read straight
+from ``L_ref`` with aligned ``pl.ds`` lane windows (Mosaic has no
+``dynamic_slice`` of a value), and flags are int32 (Mosaic cannot carry,
+reduce or select i1 vectors).  The kernel is validated in
+``interpret=True`` mode on CPU against ``kernels.ref`` and scipy, and
+compiled for a described v5e in ``tests/test_tpu_compile.py``.
 """
 from __future__ import annotations
 
@@ -41,19 +45,29 @@ DEFAULT_TILE = 128
 LANE = 128
 
 
+# Scoped VMEM a grid step may use.  v5e's scoped default is 16 MiB (of
+# 128 MiB physical); 12 MiB leaves room for the fixed ~36 KiB of small
+# blocks and for compiler scratch the model below does not count.
+VMEM_BUDGET_BYTES = 12 * 1024 * 1024
+# Words per constraint lane per problem, as the TPU compiler allocates
+# them (its scoped-VMEM reports for m_pad 2048-8192, T 40-160): the
+# (4, m_pad) block double-buffered by the grid pipeline (2 x 4, no
+# sublane padding), plus ~6 (T, m_pad) temporaries of the dense
+# re-solve (denom, num, t, lo/hi selects).  The chunked re-solve needs
+# fewer; the dense count bounds both.
+_WORDS_PER_LANE = 2 * 4 + 6
+
+
 def _pick_tile(m_pad: int, batch: int | None = None,
-               vmem_budget_bytes: int = 8 * 1024 * 1024,
                itemsize: int = 4) -> int:
-    """Choose the batch tile so one grid step's VMEM working set fits the
-    budget.  Per problem that is the packed constraint block (4 rows of
-    m_pad lanes), the c input and x output (2 + 2 words) at the solve
-    dtype's ``itemsize``, plus the int32 mv input and feas output
-    (2 * 4 bytes) — so float64 solves get half-sized tiles instead of
-    overshooting the budget 2x.  T stays a multiple of 8 (sublanes) and,
-    when the batch size is known, is clamped to ceil(batch/8)*8 so a
-    small batch is not padded all the way up to DEFAULT_TILE."""
-    bytes_per_problem = (4 * m_pad + 4) * itemsize + 2 * 4
-    t = vmem_budget_bytes // bytes_per_problem
+    """Choose the batch tile so one grid step's VMEM working set fits
+    ``VMEM_BUDGET_BYTES``: ``_WORDS_PER_LANE * m_pad`` words per problem
+    at the solve dtype's ``itemsize`` (float64 halves the tile).  T stays
+    a multiple of 8 (sublanes) and, when the batch size is known, is
+    clamped to ceil(batch/8)*8 so a small batch is not padded all the
+    way up to DEFAULT_TILE."""
+    bytes_per_problem = _WORDS_PER_LANE * m_pad * itemsize
+    t = VMEM_BUDGET_BYTES // bytes_per_problem
     t = max(8, min(DEFAULT_TILE, (t // 8) * 8))
     if batch is not None:
         t = min(t, max(8, -(-batch // 8) * 8))
@@ -62,15 +76,10 @@ def _pick_tile(m_pad: int, batch: int | None = None,
 
 def _rgb_kernel(L_ref, c_ref, mv_ref, x_ref, feas_ref, *, M: float,
                 chunk: int = 0):
-    L = L_ref[...]            # (T, 4, m_pad) packed (a_x, a_y, b, 0)
     c = c_ref[...]            # (T, 2)
     mv = mv_ref[...]          # (T, 1) int32
-    T, _, m_pad = L.shape
-    dt = L.dtype
-
-    ax = L[:, 0, :]           # (T, m_pad)
-    ay = L[:, 1, :]
-    bb = L[:, 2, :]
+    T, _, m_pad = L_ref.shape
+    dt = L_ref.dtype
 
     cx = c[:, 0:1]            # (T, 1)
     cy = c[:, 1:2]
@@ -78,16 +87,30 @@ def _rgb_kernel(L_ref, c_ref, mv_ref, x_ref, feas_ref, *, M: float,
 
     big = jnp.asarray(jnp.finfo(dt).max, dt)
     Mv = jnp.asarray(M, dt)
-    h_iota = jax.lax.broadcasted_iota(jnp.int32, (T, m_pad), 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (T, LANE), 1)
+    # Constants are typed explicitly: under x64 a Python scalar handed
+    # to a jnp function becomes an i64/f64 convert, which Mosaic
+    # cannot lower.
+    one, zero, lane_n = jnp.int32(1), jnp.int32(0), jnp.int32(LANE)
+    neg_inf = jnp.asarray(-jnp.inf, dt)
 
     def _sign_tb(v, tb):
         return jnp.where(jnp.abs(v) > oneD.EPS_TIE, jnp.sign(v),
                          jnp.where(jnp.abs(tb) > oneD.EPS_TIE,
-                                   jnp.sign(tb), 1.0))
+                                   jnp.sign(tb), jnp.ones((), dt)))
+
+    def _row_i(k, i):
+        """Component ``k`` of constraint ``i`` as (T, 1): load the
+        aligned 128-lane block holding ``i`` and pick lane ``i`` with a
+        masked max (exact: max(v, -inf) == v, sign of zero kept)."""
+        base = pl.multiple_of(jax.lax.div(i, lane_n) * lane_n, LANE)
+        blk = L_ref[:, k, pl.ds(base, LANE)]
+        return jnp.max(jnp.where(lane == i - base, blk, neg_inf),
+                       axis=1, keepdims=True)
 
     x0 = jnp.concatenate(
         [_sign_tb(cx, cpx) * Mv, _sign_tb(cy, cpy) * Mv], axis=1)  # (T, 2)
-    feas0 = jnp.ones((T, 1), jnp.bool_)
+    feas0 = jnp.ones((T, 1), jnp.int32)
     max_mv = jnp.max(mv)
 
     def cond(carry):
@@ -96,21 +119,25 @@ def _rgb_kernel(L_ref, c_ref, mv_ref, x_ref, feas_ref, *, M: float,
 
     def body(carry):
         i, x, feas = carry
-        a_ix = jax.lax.dynamic_slice_in_dim(ax, i, 1, axis=1)  # (T, 1)
-        a_iy = jax.lax.dynamic_slice_in_dim(ay, i, 1, axis=1)
-        b_i = jax.lax.dynamic_slice_in_dim(bb, i, 1, axis=1)
+        a_ix, a_iy, b_i = _row_i(0, i), _row_i(1, i), _row_i(2, i)
         lhs = a_ix * x[:, 0:1] + a_iy * x[:, 1:2]
-        violated = feas & (i < mv) & (lhs > b_i + oneD.EPS_FEAS)  # (T, 1)
+        violated = (feas > 0) & (i < mv) & (lhs > b_i + oneD.EPS_FEAS)
 
-        def resolve(xf):
-            x, feas = xf
+        def resolve(x):
             # Line frame: p0 = a_i * b_i (unit normals), u = perp(a_i).
             p0x, p0y = a_ix * b_i, a_iy * b_i
             ux, uy = -a_iy, a_ix
 
-            def _bounds_block(axc, ayc, bbc, iota_c):
-                """sigma bounds over one lane block (paper eqs. 3-4);
-                the min/max is the atomicMin/atomicMax analogue."""
+            def _bounds_block(start, width):
+                """sigma bounds over lanes [start, start + width) (paper
+                eqs. 3-4); the min/max is the atomicMin/atomicMax
+                analogue.  ``bad`` is int32 0/1: Mosaic has no i1
+                vectors in reductions or loop carries."""
+                axc = L_ref[:, 0, pl.ds(start, width)]
+                ayc = L_ref[:, 1, pl.ds(start, width)]
+                bbc = L_ref[:, 2, pl.ds(start, width)]
+                iota_c = start + jax.lax.broadcasted_iota(
+                    jnp.int32, (T, width), 1)
                 denom = axc * ux + ayc * uy
                 num = bbc - (axc * p0x + ayc * p0y)
                 is_par = jnp.abs(denom) <= oneD.EPS_DENOM
@@ -118,36 +145,30 @@ def _rgb_kernel(L_ref, c_ref, mv_ref, x_ref, feas_ref, *, M: float,
                 mask = iota_c < i
                 hi = jnp.where(mask & (denom > oneD.EPS_DENOM), t, big)
                 lo = jnp.where(mask & (denom < -oneD.EPS_DENOM), t, -big)
-                bad = jnp.any(mask & is_par & (num < -oneD.EPS_FEAS),
-                              axis=1, keepdims=True)
+                bad = jnp.max(jnp.where(
+                    mask & is_par & (num < -oneD.EPS_FEAS), one, zero),
+                    axis=1, keepdims=True)
                 return (jnp.max(lo, axis=1, keepdims=True),
                         jnp.min(hi, axis=1, keepdims=True), bad)
 
             if chunk:
                 # chunked re-solve: only ceil(i/chunk) lane blocks of WUs
                 # (work proportional to i, the true WU count)
-                n_blocks = (i + chunk - 1) // chunk
-
                 def blk(j, carry):
                     t_lo, t_hi, bad = carry
-                    axc = jax.lax.dynamic_slice_in_dim(ax, j * chunk,
-                                                       chunk, axis=1)
-                    ayc = jax.lax.dynamic_slice_in_dim(ay, j * chunk,
-                                                       chunk, axis=1)
-                    bbc = jax.lax.dynamic_slice_in_dim(bb, j * chunk,
-                                                       chunk, axis=1)
-                    iota_c = j * chunk + jax.lax.broadcasted_iota(
-                        jnp.int32, (T, chunk), 1)
-                    lo_j, hi_j, bad_j = _bounds_block(axc, ayc, bbc, iota_c)
+                    lo_j, hi_j, bad_j = _bounds_block(
+                        pl.multiple_of(j * chunk, chunk), chunk)
                     return (jnp.maximum(t_lo, lo_j),
-                            jnp.minimum(t_hi, hi_j), bad | bad_j)
+                            jnp.minimum(t_hi, hi_j),
+                            jnp.maximum(bad, bad_j))
 
+                n_blocks = jax.lax.div(i + (chunk - 1), jnp.int32(chunk))
                 t_lo, t_hi, par_bad = jax.lax.fori_loop(
-                    0, n_blocks, blk,
+                    zero, n_blocks, blk,
                     (jnp.full((T, 1), -big, dt), jnp.full((T, 1), big, dt),
-                     jnp.zeros((T, 1), jnp.bool_)))
+                     jnp.zeros((T, 1), jnp.int32)))
             else:
-                t_lo, t_hi, par_bad = _bounds_block(ax, ay, bb, h_iota)
+                t_lo, t_hi, par_bad = _bounds_block(0, m_pad)
             # --- The four box bounds, computed in closed form ---
             for bd, bn in (
                 (ux, Mv - p0x), (-ux, Mv + p0x),
@@ -157,27 +178,36 @@ def _rgb_kernel(L_ref, c_ref, mv_ref, x_ref, feas_ref, *, M: float,
                     t_hi, jnp.where(bd > oneD.EPS_DENOM, bn / bd, big))
                 t_lo = jnp.maximum(
                     t_lo, jnp.where(bd < -oneD.EPS_DENOM, bn / bd, -big))
-                par_bad = par_bad | (
-                    (jnp.abs(bd) <= oneD.EPS_DENOM) & (bn < -oneD.EPS_FEAS))
-            feas_new = (t_lo <= t_hi + oneD.EPS_FEAS) & ~par_bad
-            # Objective endpoint selection (tie -> perpendicular objective).
+                par_bad = jnp.where(
+                    (jnp.abs(bd) <= oneD.EPS_DENOM) & (bn < -oneD.EPS_FEAS),
+                    one, par_bad)
+            feas_new = jnp.where((t_lo <= t_hi + oneD.EPS_FEAS)
+                                 & (par_bad == 0), one, zero)
+            # Objective endpoint selection (tie -> perpendicular objective),
+            # written with & / | because Mosaic cannot select between
+            # i1 vectors.
             cu = cx * ux + cy * uy
             cpu = cpx * ux + cpy * uy
-            pick_hi = jnp.where(jnp.abs(cu) > oneD.EPS_TIE, cu > 0.0,
-                                cpu > 0.0)
+            tie = jnp.abs(cu) <= oneD.EPS_TIE
+            pick_hi = (~tie & (cu > 0.0)) | (tie & (cpu > 0.0))
             tt = jnp.where(pick_hi, t_hi, t_lo)
             x_new = jnp.concatenate([p0x + tt * ux, p0y + tt * uy], axis=1)
-            x = jnp.where(violated, x_new, x)
-            feas = jnp.where(violated, feas & feas_new, feas)
-            return x, feas
+            return x_new, feas_new
 
-        x, feas = jax.lax.cond(jnp.any(violated), resolve, lambda xf: xf,
-                               (x, feas))
+        # The branches return candidates; the select over ``violated``
+        # stays outside the cond so no i1 vector crosses its boundary.
+        # An int32 max, not jnp.any: Mosaic reduces bools through a
+        # float of the default width, which is f64 under x64.
+        any_violated = jnp.max(jnp.where(violated, one, zero)) > 0
+        x_new, feas_new = jax.lax.cond(
+            any_violated, resolve, lambda x: (x, feas), x)
+        x = jnp.where(violated, x_new, x)
+        feas = jnp.where(violated, feas_new, feas)
         return i + 1, x, feas
 
     _, x, feas = jax.lax.while_loop(cond, body, (jnp.int32(0), x0, feas0))
     x_ref[...] = x
-    feas_ref[...] = feas.astype(jnp.int32)
+    feas_ref[...] = feas
 
 
 def rgb_pallas(
@@ -200,20 +230,30 @@ def rgb_pallas(
         raise ValueError(f"m_pad {m_pad} not a multiple of {LANE}")
     grid = (B // T,)
     flops_resolve = 12 * m_pad  # per problem per violation, approx
-    if chunk and m_pad % chunk:
-        raise ValueError(f"m_pad {m_pad} % chunk {chunk} != 0")
+    if chunk and (m_pad % chunk or chunk % LANE):
+        raise ValueError(f"chunk {chunk} must be a multiple of {LANE} "
+                         f"dividing m_pad {m_pad}")
     kernel = functools.partial(_rgb_kernel, M=M, chunk=chunk)
+
+    # Index maps return typed zeros: a bare 0 is i64 under x64, and
+    # Mosaic cannot return it.
+    def rows3(t):
+        return t, jnp.int32(0), jnp.int32(0)
+
+    def rows2(t):
+        return t, jnp.int32(0)
+
     x, feas = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((T, 4, m_pad), lambda t: (t, 0, 0)),
-            pl.BlockSpec((T, 2), lambda t: (t, 0)),
-            pl.BlockSpec((T, 1), lambda t: (t, 0)),
+            pl.BlockSpec((T, 4, m_pad), rows3),
+            pl.BlockSpec((T, 2), rows2),
+            pl.BlockSpec((T, 1), rows2),
         ],
         out_specs=[
-            pl.BlockSpec((T, 2), lambda t: (t, 0)),
-            pl.BlockSpec((T, 1), lambda t: (t, 0)),
+            pl.BlockSpec((T, 2), rows2),
+            pl.BlockSpec((T, 1), rows2),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, 2), L.dtype),

@@ -18,8 +18,8 @@ Layers::
               p99-threshold flushes
     log       stdlib-logging JSON formatter with trace_id/span_id/
               tenant/bucket injected from the active context
-    profiler  opt-in jax.profiler TraceAnnotation / start_trace hooks
-              so device traces line up with host spans
+    profiler  opt-in jax.profiler start_trace/stop_trace session so
+              device traces line up with host spans
 
 The span taxonomy (see README "Observability" for the full table):
 ``rpc.handle`` -> ``admit`` -> ``request`` -> ``queue.wait`` ->

@@ -1,16 +1,11 @@
-"""Opt-in jax.profiler hooks that line device traces up with host spans.
+"""Opt-in jax.profiler session that lines device traces up with host
+spans: :class:`ProfileSession` brackets a whole run
+(``--jax-profile-dir``) with ``jax.profiler.start_trace`` /
+``stop_trace``.  The scheduler and sharding layers label dispatches
+with ``jax.profiler.TraceAnnotation`` named like their host spans.
 
-Two thin wrappers, both import-gated so the obs package never drags
-jax in (and keeps working when jax is absent):
-
-* :func:`annotation` — a context manager emitting a
-  ``jax.profiler.TraceAnnotation`` named like the host span, so the
-  per-launch dispatch shows up as a labelled region in a device
-  profile.  Falls back to a null context when jax (or the profiler)
-  is unavailable.
-* :class:`ProfileSession` — ``jax.profiler.start_trace`` /
-  ``stop_trace`` bracketing for a whole bench run
-  (``--jax-profile-dir``), tolerant of double-stops and missing jax.
+Profiler failures raise: a run asked for a trace must not exit 0
+without one.
 
 Caveat (documented in the README): on CPU backends the device
 "profile" is host threads running compiled XLA code — annotations
@@ -19,42 +14,18 @@ against; treat CPU profiles as structural, not quantitative.
 """
 from __future__ import annotations
 
-import contextlib
-from typing import Any, Iterator, Optional
+from typing import Any, Optional
 
-try:  # pragma: no cover - exercised only when jax present (it is in CI)
-    import jax.profiler as _jax_profiler
-except Exception:  # pragma: no cover
-    _jax_profiler = None
-
-
-def available() -> bool:
-    return _jax_profiler is not None
-
-
-@contextlib.contextmanager
-def annotation(name: str) -> Iterator[None]:
-    """``jax.profiler.TraceAnnotation(name)`` when jax is importable,
-    else a no-op block."""
-    if _jax_profiler is None:
-        yield
-        return
-    try:
-        cm: Any = _jax_profiler.TraceAnnotation(name)
-    except Exception:
-        yield
-        return
-    with cm:
-        yield
+import jax.profiler as _jax_profiler
 
 
 class ProfileSession:
     """Start/stop a jax profiler trace around a run.
 
-    ``ProfileSession(log_dir).start()`` is a no-op (returning False)
-    when jax or its profiler is unavailable; ``stop()`` tolerates
-    never-started and double-stop so shutdown paths can call it
-    unconditionally.
+    ``start()`` is a no-op (returning False) without a ``log_dir`` or
+    when already started; ``stop()`` tolerates never-started and
+    double-stop so shutdown paths can call it unconditionally.  Errors
+    from the profiler itself propagate.
     """
 
     def __init__(self, log_dir: Optional[str]):
@@ -62,12 +33,9 @@ class ProfileSession:
         self.active = False
 
     def start(self) -> bool:
-        if not self.log_dir or _jax_profiler is None or self.active:
+        if not self.log_dir or self.active:
             return False
-        try:
-            _jax_profiler.start_trace(self.log_dir)
-        except Exception:
-            return False
+        _jax_profiler.start_trace(self.log_dir)
         self.active = True
         return True
 
@@ -75,10 +43,7 @@ class ProfileSession:
         if not self.active:
             return False
         self.active = False
-        try:
-            _jax_profiler.stop_trace()
-        except Exception:
-            return False
+        _jax_profiler.stop_trace()
         return True
 
     def __enter__(self) -> "ProfileSession":

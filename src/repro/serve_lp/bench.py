@@ -50,10 +50,13 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.serve_lp.scheduler import BatchScheduler
 from repro.solver import SolverSpec
 
 KINDS = ("feasible", "infeasible", "degenerate")
+# Constraint counts traffic draws from, cut at BenchConfig.m_max.
+M_LADDER = (8, 16, 32, 64, 128, 256, 512, 1024, 2048, 4096)
 
 
 @dataclasses.dataclass
@@ -137,7 +140,7 @@ def make_request(cfg: BenchConfig, i: int):
     """Request #i of the stream — a pure function of (seed, i)."""
     rng = np.random.default_rng(
         np.random.SeedSequence([cfg.seed, i, 0x52E41]))
-    sizes = [m for m in (8, 16, 32, 64, 128, 256, 512, 1024)
+    sizes = [m for m in M_LADDER
              if cfg.m_min <= m <= cfg.m_max]
     m = int(sizes[rng.integers(len(sizes))])
     kind = KINDS[rng.choice(3, p=np.asarray(cfg.kind_mix))]
@@ -157,9 +160,8 @@ def _warmup(cfg: BenchConfig, sched: BatchScheduler,
     from repro.serve_lp.metrics import ServeMetrics
     t0 = time.perf_counter()
     rng = np.random.default_rng(np.random.SeedSequence([cfg.seed, 0xAA]))
-    buckets = sorted({bucket_m(m, base=sched.bucket_base) for m in
-                      (8, 16, 32, 64, 128, 256, 512, 1024)
-                      if cfg.m_min <= m <= cfg.m_max})
+    buckets = sorted({bucket_m(m, base=sched.bucket_base)
+                      for m in M_LADDER if cfg.m_min <= m <= cfg.m_max})
     # b_pad ladder: a flush holds 1..max_batch requests, so its b_pad is
     # one of the unit*2^k rungs up to bucket_batch(max_batch, unit).
     rungs, b = set(), sched.batch_unit
@@ -407,7 +409,7 @@ def run_rpc_traffic(cfg: BenchConfig, *, quiet: bool = False) -> Dict:
             conn = connect()
             rng = np.random.default_rng(
                 np.random.SeedSequence([cfg.seed, 0xAB]))
-            sizes = [m for m in (8, 16, 32, 64, 128, 256, 512, 1024)
+            sizes = [m for m in M_LADDER
                      if cfg.m_min <= m <= cfg.m_max]
             for m in sizes:
                 A, b, c = _feasible(rng, m)
@@ -643,6 +645,7 @@ def main(argv=None) -> None:
                          "sheds with 429 + Retry-After, and answers "
                          "match direct solves (--rpc)")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     if args.smoke:
         cfg = smoke_config()

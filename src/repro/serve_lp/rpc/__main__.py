@@ -10,6 +10,7 @@ import argparse
 import asyncio
 import os
 
+from repro.compile_cache import use_compile_cache
 from repro.obs import FlightRecorder, Tracer, setup_logging
 from repro.obs.profiler import ProfileSession
 from repro.serve_lp.rpc.admission import AdmissionPolicy
@@ -42,6 +43,7 @@ def _maybe_init_distributed() -> None:
 
 def main(argv=None) -> None:
     _maybe_init_distributed()
+    use_compile_cache()
     ap = argparse.ArgumentParser(
         prog="python -m repro.serve_lp.rpc",
         description="HTTP front end for the batched 2-D LP solver")

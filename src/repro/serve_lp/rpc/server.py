@@ -672,16 +672,17 @@ def make_frontend(spec=None, *,
                   target_p99_s: Optional[float] = None,
                   metrics=None,
                   tracer=None,
-                  recorder=None) -> LPFrontend:
+                  recorder=None,
+                  devices=None) -> LPFrontend:
     """Build scheduler + admission + quota + SLO in one call — the
     shared construction path of ``__main__``, the bench's ``--rpc``
-    mode, and tests.  ``tracer``/``recorder`` are handed to the
-    scheduler; the frontend picks them up from there."""
+    mode, and tests.  ``tracer``/``recorder``/``devices`` are handed to
+    the scheduler; the frontend picks them up from there."""
     from repro.serve_lp.scheduler import BatchScheduler
     scheduler = BatchScheduler(
         spec, max_batch=max_batch, max_wait_s=max_wait_s,
         max_inflight=max_inflight, pipeline=pipeline, metrics=metrics,
-        tracer=tracer, recorder=recorder)
+        tracer=tracer, recorder=recorder, devices=devices)
     slo = (SLOController(target_p99_s)
            if target_p99_s is not None else None)
     return LPFrontend(scheduler, policy=policy, quotas=quotas, slo=slo)

@@ -87,7 +87,6 @@ import numpy as np
 
 from repro.core.lp import PAD_B
 from repro.kernels.batch_lp import LANE
-from repro.obs.profiler import annotation as _device_annotation
 from repro.obs.recorder import FlightRecorder
 from repro.obs.trace import (NOOP_TRACER, TraceContext, Tracer,
                              new_trace_context)
@@ -1054,7 +1053,7 @@ class BatchScheduler:
         L, c, mv = unit.bufs
         try:
             if tracer.annotate_device:
-                with _device_annotation(unit.name):
+                with jax.profiler.TraceAnnotation(unit.name):
                     unit.handle = unit.exe.dispatch(L, c, mv)
             else:
                 unit.handle = unit.exe.dispatch(L, c, mv)

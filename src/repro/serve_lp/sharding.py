@@ -10,7 +10,7 @@ over devices:
   The planner owns padding: ``b_pad`` only needs to be positive — rows
   are padded with neutral LPs up to whole kernel tiles here, never up
   to ``tile * n_devices`` blocks, so a prime-sized flush on 4 devices
-  is legal.  A single local device compiles to plain ``jax.jit``
+  is legal.  A group of one device compiles to plain ``jax.jit``
   (identical to the pre-mesh path).
 * ``"pmap"`` — the legacy path, kept as a one-release escape hatch:
   ``jax.pmap`` splits the batch evenly over *all* devices and requires
@@ -48,12 +48,11 @@ from typing import Any, Callable, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax.sharding import NamedSharding, SingleDeviceSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.core.packed import PackedLPBatch
 from repro.core.lp import PAD_B
-from repro.obs.profiler import annotation as _device_annotation
 from repro.serve_lp.buckets import ExecSpec
 from repro.serve_lp.mesh_layout import (
     DATA_AXIS,
@@ -173,34 +172,41 @@ def _pad_rows(L, c, mv, b_pad: int):
             np.concatenate([mv, mvp]))
 
 
+def group_launch(solve, group_devs, donate_kw=None):
+    """One launch group's jitted solve and the input placement for it:
+    plain ``jax.jit`` on a single device, ``jax.jit(shard_map)`` over a
+    1-D mesh of several.  Inputs are put on their devices with the
+    returned sharding before the call: left to jit, uncommitted host
+    numpy lands on the default device first."""
+    donate_kw = donate_kw or {}
+    if len(group_devs) == 1:
+        return (jax.jit(solve, **donate_kw),
+                SingleDeviceSharding(group_devs[0]))
+    mesh = make_mesh(group_devs)
+    # check_vma=False: every in/out is sharded over DATA_AXIS (nothing
+    # replicated to check) and the pallas_call kernel backend has no
+    # replication rule at all.
+    fn = jax.jit(
+        jax.shard_map(
+            solve, mesh=mesh,
+            in_specs=(P(DATA_AXIS), P(DATA_AXIS), P(DATA_AXIS)),
+            out_specs=(P(DATA_AXIS), P(DATA_AXIS)),
+            check_vma=False),
+        **donate_kw)
+    return fn, NamedSharding(mesh, P(DATA_AXIS))
+
+
 def _build_mesh_executable(spec: ExecSpec, devices, solve,
                            donate_kw) -> Executable:
     """Plan a :class:`MeshLayout` for the spec and compile one
-    ``shard_map`` launch per :class:`LaunchGroup` (uneven layouts need
-    at most two).  Each group jits over its own contiguous sub-mesh,
-    so group launches land on disjoint devices and overlap."""
+    launch per :class:`LaunchGroup` (uneven layouts need at most two).
+    Each group jits over its own contiguous devices, so group launches
+    land on disjoint devices and overlap."""
     layout = plan_layout(spec.b_pad, spec.tile, len(devices))
-
-    launches = []
-    for g in layout.groups:
-        group_devs = devices[g.start:g.start + g.n_devices]
-        if len(group_devs) == 1 and len(devices) == 1:
-            # Single local device: plain jit, identical to the
-            # pre-mesh path (no mesh machinery to pay for).
-            fn = jax.jit(solve, **donate_kw)
-        else:
-            mesh = make_mesh(group_devs)
-            # check_rep=False: every in/out is sharded over DATA_AXIS
-            # (nothing replicated to check) and the pallas_call kernel
-            # backend has no replication rule at all.
-            fn = jax.jit(
-                shard_map(
-                    solve, mesh=mesh,
-                    in_specs=(P(DATA_AXIS), P(DATA_AXIS), P(DATA_AXIS)),
-                    out_specs=(P(DATA_AXIS), P(DATA_AXIS)),
-                    check_rep=False),
-                **donate_kw)
-        launches.append((g.offset, g.rows, fn))
+    launches = [
+        (g.offset, g.rows) + group_launch(
+            solve, devices[g.start:g.start + g.n_devices], donate_kw)
+        for g in layout.groups]
 
     b_pad = spec.b_pad
     labels = tuple(
@@ -210,14 +216,16 @@ def _build_mesh_executable(spec: ExecSpec, devices, solve,
     def dispatch(L, c, mv):
         if L.shape[0] != layout.b_pad:
             L, c, mv = _pad_rows(L, c, mv, layout.b_pad)
-        if _ANNOTATE_LAUNCHES:
-            out = []
-            for (o, n, fn), label in zip(launches, labels):
-                with _device_annotation(label):
-                    out.append(fn(L[o:o + n], c[o:o + n], mv[o:o + n]))
-            return tuple(out)
-        return tuple(fn(L[o:o + n], c[o:o + n], mv[o:o + n])
-                     for o, n, fn in launches)
+        out = []
+        for (o, n, fn, placement), label in zip(launches, labels):
+            args = jax.device_put((L[o:o + n], c[o:o + n], mv[o:o + n]),
+                                  placement)
+            if _ANNOTATE_LAUNCHES:
+                with jax.profiler.TraceAnnotation(label):
+                    out.append(fn(*args))
+            else:
+                out.append(fn(*args))
+        return tuple(out)
 
     def complete(handles):
         xs = [np.asarray(h[0]) for h in handles]

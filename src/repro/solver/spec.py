@@ -216,6 +216,11 @@ class SolverSpec:
         if backend == "auto":
             backend = "kernel" if platform == "tpu" else "rgb"
         if backend == "kernel":
+            if platform == "tpu" and self.dtype == "float64":
+                raise ValueError(
+                    "backend='kernel' cannot solve float64 on TPU: Mosaic "
+                    "lowers 32-bit types only; use dtype='float32', or "
+                    "backend='rgb'/'naive' for float64")
             interpret = (platform == "cpu" if self.interpret is None
                          else bool(self.interpret))
         else:

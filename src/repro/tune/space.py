@@ -44,8 +44,6 @@ KERNEL_CHUNKS = (0, 128, 256)
 PDHG_ITER_BLOCKS = (32, 64, 128)
 PDHG_RESTART_PERIODS = (0, 512, 2048)
 
-VMEM_BUDGET_BYTES = 8 * 1024 * 1024  # matches _pick_tile's budget
-
 
 @dataclasses.dataclass(frozen=True)
 class Candidate:
@@ -108,9 +106,7 @@ def candidate_space(
         elif backend == "kernel":
             m_lane = -(-m_pad // LANE) * LANE
             # largest VMEM-feasible tile for this shape/dtype
-            t_max = _pick_tile(m_lane, None,
-                               vmem_budget_bytes=VMEM_BUDGET_BYTES,
-                               itemsize=itemsize)
+            t_max = _pick_tile(m_lane, None, itemsize=itemsize)
             for tile in KERNEL_TILES:
                 if tile > min(t_max, DEFAULT_TILE, batch_cap) \
                         and tile != KERNEL_TILES[0]:

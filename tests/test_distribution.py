@@ -4,23 +4,7 @@ Tests run in subprocesses so the main pytest process keeps exactly one
 visible device (conftest.run_multidevice)."""
 import textwrap
 
-import jax
 import pytest
-
-# The state-space / pipeline model stack calls jax.lax.pvary, which this
-# container's jax (0.4.37) predates.  Version-gate those tests (they are
-# model-stack only, unrelated to the LP path) so tier-1 runs green and a
-# real regression is visible; on a jax with pvary they run normally.
-needs_pvary = pytest.mark.skipif(
-    not hasattr(jax.lax, "pvary"),
-    reason="jax.lax.pvary unavailable in this jax (needs >= 0.6); "
-           "pre-existing model-stack limitation, see ROADMAP.md")
-
-# Same story for jax.tree.leaves_with_path (elastic reshard test only).
-needs_tree_paths = pytest.mark.skipif(
-    not hasattr(jax.tree, "leaves_with_path"),
-    reason="jax.tree.leaves_with_path unavailable in this jax; "
-           "pre-existing model-stack limitation, see ROADMAP.md")
 
 GRAD_SNIPPET = textwrap.dedent("""
     import jax, jax.numpy as jnp, numpy as np, dataclasses
@@ -70,8 +54,8 @@ GRAD_SNIPPET = textwrap.dedent("""
     ("granite-8b", (1, 4)),
     ("granite-8b", (2, 2)),
     ("qwen2-0.5b", (2, 2)),
-    pytest.param("mamba2-1.3b", (2, 2), marks=needs_pvary),
-    pytest.param("zamba2-2.7b", (2, 2), marks=needs_pvary),
+    ("mamba2-1.3b", (2, 2)),
+    ("zamba2-2.7b", (2, 2)),
     ("whisper-base", (1, 4)),
     ("paligemma-3b", (2, 2)),
 ])
@@ -86,7 +70,6 @@ def test_tp_grads_match_single_device(multidevice, arch, mesh_shape):
     assert "OK" in multidevice(code, n_devices=4)
 
 
-@needs_pvary
 @pytest.mark.parametrize("arch", ["olmoe-1b-7b", "arctic-480b"])
 def test_moe_tp_exact_when_capacity_matches(multidevice, arch):
     """TP=4, DP=1 -> identical capacity to single device -> exact grads."""
@@ -175,7 +158,6 @@ def test_manual_comm_matches_auto(multidevice):
     assert "OK" in multidevice(code, n_devices=4)
 
 
-@needs_tree_paths
 def test_elastic_reshard_checkpoint(multidevice, tmp_path):
     """Save on a (2,2) mesh, restore onto (4,1) and (1,4): the logical
     state must be identical (elastic rescaling)."""

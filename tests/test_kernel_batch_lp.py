@@ -9,7 +9,8 @@ from _hypothesis_compat import given, settings, st
 from repro.core import (infeasible_lp, normalize_batch, ragged_feasible_lp,
                         random_feasible_lp, shuffle_batch)
 from repro.kernels import ops, ref
-from repro.kernels.batch_lp import _pick_tile
+from repro.kernels.batch_lp import (VMEM_BUDGET_BYTES, _WORDS_PER_LANE,
+                                    _pick_tile)
 from repro.solver import SolverSpec, get_solver
 
 
@@ -77,34 +78,37 @@ def test_kernel_tile_sizes(tile):
 
 
 def test_pick_tile_vmem_budget():
-    # The full working set (constraints + c input + x output at the
-    # solve dtype, plus int32 mv/feas) must stay within the default 8MB
-    # budget at every itemsize
+    # The modelled working set (the double-buffered (4, m_pad) block
+    # plus the dense re-solve's (T, m_pad) temporaries, 14 words per
+    # lane) must stay within the scoped-VMEM budget at every itemsize
     for itemsize in (4, 8):
         for m_pad in (128, 1024, 8192, 65536):
             t = _pick_tile(m_pad, itemsize=itemsize)
             assert t >= 8 and t % 8 == 0
-            working_set = t * ((4 * m_pad + 4) * itemsize + 8)
-            assert working_set <= 8 * 1024 * 1024 or t == 8
+            working_set = t * _WORDS_PER_LANE * m_pad * itemsize
+            assert working_set <= VMEM_BUDGET_BYTES or t == 8
 
 
 def test_pick_tile_pinned():
     # Pin chosen tiles for representative (B, m_pad) pairs so VMEM-model
-    # changes are deliberate, not accidental.
+    # changes are deliberate, not accidental.  The model counts 14 words
+    # per constraint lane per problem (2 x 4 for the double-buffered
+    # input block, 6 for the dense re-solve's temporaries) against a
+    # 12 MiB budget under v5e's 16 MiB scoped-VMEM limit.
     assert _pick_tile(128) == 128
     assert _pick_tile(512) == 128
-    assert _pick_tile(8192) == 56
+    assert _pick_tile(8192) == 24
     assert _pick_tile(65536) == 8      # floor: minimum viable tile
     # batch clamp: small batches get small tiles (multiple of 8 >= B)
     assert _pick_tile(128, 20) == 24
     assert _pick_tile(128, 4) == 8
     assert _pick_tile(128, 1000) == 128
-    assert _pick_tile(8192, 48) == 48
-    # float64 working sets are ~2x: tiles shrink instead of overshooting
-    # the VMEM budget (the old estimate hardcoded 4-byte elements)
+    assert _pick_tile(4096, 40) == 40
+    # float64 working sets are 2x: tiles shrink instead of overshooting
+    # the VMEM budget
     assert _pick_tile(128, itemsize=8) == 128
-    assert _pick_tile(8192, itemsize=8) == 24
-    assert _pick_tile(65536, itemsize=8) == 8
+    assert _pick_tile(8192, itemsize=8) == 8
+    assert _pick_tile(4096, itemsize=8) == 24
 
 
 @settings(max_examples=10, deadline=None)

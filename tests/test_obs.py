@@ -24,6 +24,7 @@ from repro.obs import (FlightRecorder, NOOP_TRACER, SpanBuffer, Tracer,
                        setup_logging, to_chrome_trace, use_context)
 from repro.obs.export import validate_chrome_trace
 from repro.obs.log import JsonFormatter, TextFormatter
+from repro.obs.profiler import ProfileSession
 from repro.obs.trace import (Span, flush_membership, span_index,
                              spans_for_trace)
 from repro.serve_lp import BatchScheduler, ExecutableCache, SolverSpec
@@ -364,6 +365,19 @@ def test_json_log_formatter_binds_trace_context():
     assert lines[0]["level"] == "INFO"
     assert "trace_id" not in lines[1]
     assert current_context() == {}
+
+
+def test_profile_session_surfaces_profiler_errors(tmp_path):
+    # A run asked for a trace must not exit 0 without one: the second
+    # concurrent session's start_trace failure propagates.
+    first = ProfileSession(str(tmp_path / "a"))
+    assert first.start()
+    try:
+        with pytest.raises(RuntimeError):
+            ProfileSession(str(tmp_path / "b")).start()
+    finally:
+        assert first.stop()
+    assert not first.stop()
 
 
 def test_text_formatter_and_setup_validation():

@@ -1,14 +1,7 @@
 """Pipeline-parallel engine correctness (subprocess multi-device)."""
 import textwrap
 
-import jax
-import pytest
 
-
-@pytest.mark.skipif(
-    not hasattr(jax.lax, "pvary"),
-    reason="jax.lax.pvary unavailable in this jax (needs >= 0.6); "
-           "pre-existing model-stack limitation, see ROADMAP.md")
 def test_gpipe_matches_sequential(multidevice):
     code = textwrap.dedent("""
         import jax, jax.numpy as jnp, numpy as np, functools

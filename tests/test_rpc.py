@@ -77,6 +77,14 @@ def frontend_slo():
     f.close()
 
 
+def test_make_frontend_pins_devices():
+    f = make_frontend(SPEC, devices=jax.devices()[:1])
+    try:
+        assert f.scheduler.n_devices == 1
+    finally:
+        f.close()
+
+
 # -- token buckets --------------------------------------------------------
 
 def test_token_bucket_refill_and_pricing():

@@ -122,6 +122,16 @@ def test_float64_requires_x64():
         solve_with_spec(spec, random_feasible_lp(jax.random.key(0), 2, 4))
 
 
+def test_float64_kernel_refused_on_tpu():
+    """Mosaic lowers 32-bit types only: a float64 kernel spec fails at
+    resolution on TPU, not deep in lowering."""
+    spec = SolverSpec(backend="kernel", dtype="float64")
+    with jax.enable_x64(True):
+        with pytest.raises(ValueError, match="float64 on TPU"):
+            spec.resolve(platform="tpu")
+        assert spec.resolve(platform="cpu").interpret is True
+
+
 def test_spec_as_static_jit_argument():
     lp = random_feasible_lp(jax.random.key(0), 8, 12)
     calls = []
