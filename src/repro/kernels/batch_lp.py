@@ -265,5 +265,6 @@ def rgb_pallas(
             transcendentals=0,
         ),
         interpret=interpret,
+        name="rgb_lp",
     )(L, c, m_valid)
     return x, feas

@@ -54,6 +54,14 @@ from repro.solver.spec import RGB_DEFAULT_TILE, SolverSpec
 
 AnyLPBatch = Union[LPBatch, PackedLPBatch]
 
+# The named scopes every device op of a solve falls under, one each, so
+# that a profile splits a call into its stages: cast/normalise/shuffle/
+# pack, padding to the kernel's lane and tile multiples, the backend, and
+# slicing the padding off with the objective.
+PREPARE, PAD, KERNEL, FINISH = ("solver.prepare", "solver.pad",
+                                "solver.kernel", "solver.finish")
+STAGES = (PREPARE, PAD, KERNEL, FINISH)
+
 
 def solve_with_spec(spec: SolverSpec, batch: AnyLPBatch,
                     key=None) -> LPSolution:
@@ -67,60 +75,51 @@ def solve_with_spec(spec: SolverSpec, batch: AnyLPBatch,
     is_packed = isinstance(batch, PackedLPBatch)
     m = batch.m_pad if is_packed else batch.m
     spec = spec.resolve_for_shape(m, batch.batch)
+    with jax.named_scope(PREPARE):
+        if key is None and spec.shuffle:
+            key = jax.random.key(spec.seed)
+        batch = _prepare(spec, batch, key)
+        if spec.backend == "kernel" and not is_packed:
+            batch = pack(batch)
+    if spec.backend == "kernel":
+        return _solve_kernel(spec, batch)
+    with jax.named_scope(KERNEL):
+        return _solve_dense(spec, batch)
+
+
+def _prepare(spec: SolverSpec, batch: AnyLPBatch, key) -> AnyLPBatch:
+    """Cast -> normalise -> shuffle, each in the batch's own layout (a
+    packed batch never leaves SoA)."""
     dt = jnp.dtype(spec.dtype)
-    if key is None and spec.shuffle:
-        key = jax.random.key(spec.seed)
-    if is_packed:
-        return _solve_packed(spec, batch, dt, key)
     # Cast each array (astype is the identity when already dt): A alone
     # matching must not let a mixed-dtype b or c leak through.
+    if isinstance(batch, PackedLPBatch):
+        batch = PackedLPBatch(L=batch.L.astype(dt), c=batch.c.astype(dt),
+                              m_valid=batch.m_valid)
+        if spec.normalize:
+            batch = normalize_packed(batch)
+        return batch if key is None else shuffle_packed(key, batch)
     batch = LPBatch(A=batch.A.astype(dt), b=batch.b.astype(dt),
                     c=batch.c.astype(dt), m_valid=batch.m_valid)
     if spec.normalize:
         batch = normalize_batch(batch)
-    if key is not None:
-        batch = shuffle_batch(key, batch)
-    if spec.backend == "kernel":
-        return _solve_kernel(spec, pack(batch))
-    return _solve_dense(spec, batch)
+    return batch if key is None else shuffle_batch(key, batch)
 
 
-def _solve_packed(spec: SolverSpec, pb: PackedLPBatch, dt,
-                  key) -> LPSolution:
-    """The packed-native pipeline: cast -> normalise -> shuffle without
-    leaving the SoA layout, then hand the ``L`` rows straight to the
-    backend (kernel and dense alike — no unpack in the trace)."""
-    pb = PackedLPBatch(L=pb.L.astype(dt), c=pb.c.astype(dt),
-                       m_valid=pb.m_valid)
-    if spec.normalize:
-        pb = normalize_packed(pb)
-    if key is not None:
-        pb = shuffle_packed(key, pb)
-    if spec.backend == "kernel":
-        return _solve_kernel(spec, pb)
+def _solve_dense(spec: SolverSpec, batch: AnyLPBatch) -> LPSolution:
+    """The jnp backends; packed batches hand their ``L`` rows straight
+    to the packed twin (no unpack in the trace)."""
+    packed = isinstance(batch, PackedLPBatch)
     if spec.backend == "pdhg":
-        return solve_pdhg_packed(pb, M=spec.M, tol=spec.tol,
-                                 max_iters=spec.max_iters,
-                                 iter_block=spec.iter_block,
-                                 restart_period=spec.restart_period)
+        return (solve_pdhg_packed if packed else solve_pdhg)(
+            batch, M=spec.M, tol=spec.tol, max_iters=spec.max_iters,
+            iter_block=spec.iter_block, restart_period=spec.restart_period)
     if spec.backend == "naive":
-        return solve_naive_packed(pb, M=spec.M)
-    return solve_rgb_packed(pb, M=spec.M,
-                            tile=spec.tile or RGB_DEFAULT_TILE,
-                            chunk=spec.chunk or 0)
-
-
-def _solve_dense(spec: SolverSpec, batch: LPBatch) -> LPSolution:
-    if spec.backend == "pdhg":
-        return solve_pdhg(batch, M=spec.M, tol=spec.tol,
-                          max_iters=spec.max_iters,
-                          iter_block=spec.iter_block,
-                          restart_period=spec.restart_period)
-    if spec.backend == "naive":
-        return solve_naive(batch, M=spec.M)
-    return solve_rgb(batch, M=spec.M,
-                     tile=spec.tile or RGB_DEFAULT_TILE,
-                     chunk=spec.chunk or 0)
+        return (solve_naive_packed if packed else solve_naive)(batch,
+                                                               M=spec.M)
+    return (solve_rgb_packed if packed else solve_rgb)(
+        batch, M=spec.M, tile=spec.tile or RGB_DEFAULT_TILE,
+        chunk=spec.chunk or 0)
 
 
 def _solve_kernel(spec: SolverSpec, pb: PackedLPBatch) -> LPSolution:
@@ -129,18 +128,22 @@ def _solve_kernel(spec: SolverSpec, pb: PackedLPBatch) -> LPSolution:
     from repro.kernels.batch_lp import LANE, _pick_tile, rgb_pallas
 
     B = pb.batch
-    pb = pad_packed(pb, -(-pb.m_pad // LANE) * LANE)
-    tile = spec.tile or _pick_tile(pb.m_pad, B,
-                                   itemsize=pb.L.dtype.itemsize)
-    run = pad_packed_batch_dim(pb, -(-B // tile) * tile)
-    x, feas = rgb_pallas(run.L, run.c, run.m_valid, M=spec.M, tile=tile,
-                         chunk=spec.chunk, interpret=spec.interpret)
-    x, feas = x[:B], feas[:B, 0]
-    return LPSolution(
-        x=x,
-        feasible=feas.astype(bool),
-        objective=jnp.einsum("bd,bd->b", pb.c.astype(x.dtype), x),
-    )
+    with jax.named_scope(PAD):
+        pb = pad_packed(pb, -(-pb.m_pad // LANE) * LANE)
+        tile = spec.tile or _pick_tile(pb.m_pad, B,
+                                       itemsize=pb.L.dtype.itemsize)
+        run = pad_packed_batch_dim(pb, -(-B // tile) * tile)
+    with jax.named_scope(KERNEL):
+        x, feas = rgb_pallas(run.L, run.c, run.m_valid, M=spec.M,
+                             tile=tile, chunk=spec.chunk,
+                             interpret=spec.interpret)
+    with jax.named_scope(FINISH):
+        x, feas = x[:B], feas[:B, 0]
+        return LPSolution(
+            x=x,
+            feasible=feas.astype(bool),
+            objective=jnp.einsum("bd,bd->b", pb.c.astype(x.dtype), x),
+        )
 
 
 class Solver:
@@ -162,15 +165,21 @@ class Solver:
         # on the resolved spec, so it pins "auto" to the platform
         # default; build a Solver via ``spec.build()`` to keep the
         # shape-dependent behaviour.
-        self._solve_spec = spec if spec.backend == "auto" else self.spec
+        solve_spec = spec if spec.backend == "auto" else self.spec
+        self._solve_spec = solve_spec
+
         # jax.jit itself caches one compile per input shape/dtype; one
-        # persistent wrapper per calling convention is all we need.
-        # _shapes only tracks the distinct entries for introspection.
-        self._jit_plain = jax.jit(
-            lambda b: solve_with_spec(self._solve_spec, b))
-        self._jit_keyed = jax.jit(
-            lambda b, k: solve_with_spec(self._solve_spec, b, k))
-        self._shapes = set()
+        # persistent wrapper per calling convention is all we need.  The
+        # functions' names name the compiled modules (``jit_solve``,
+        # ``jit_solve_keyed``) and their dispatches in a profile.
+        def solve(batch):
+            return solve_with_spec(solve_spec, batch)
+
+        def solve_keyed(batch, key):
+            return solve_with_spec(solve_spec, batch, key)
+
+        self._jit_plain = jax.jit(solve)
+        self._jit_keyed = jax.jit(solve_keyed)
 
     # -- composable entry point ------------------------------------------
 
@@ -183,13 +192,14 @@ class Solver:
 
     def solve(self, batch: AnyLPBatch, key=None) -> LPSolution:
         """Solve one batch (AoS or packed) through the per-shape
-        compile cache."""
-        arr = batch.L if isinstance(batch, PackedLPBatch) else batch.A
-        self._shapes.add((type(batch).__name__, arr.shape,
-                          str(arr.dtype), key is not None))
-        if key is None:
-            return self._jit_plain(batch)
-        return self._jit_keyed(batch, key)
+        compile cache.  A ``solver.solve`` profiler span covers the
+        host from entry until the asynchronous dispatch returns."""
+        m = batch.m_pad if isinstance(batch, PackedLPBatch) else batch.m
+        with jax.profiler.TraceAnnotation("solver.solve", batch=batch.batch,
+                                          m=m):
+            if key is None:
+                return self._jit_plain(batch)
+            return self._jit_keyed(batch, key)
 
     def solve_one(self, A, b, c, key=None) -> LPSolution:
         """Solve a single LP (``A (m,2)``, ``b (m,)``, ``c (2,)``);
@@ -198,14 +208,6 @@ class Solver:
         sol = self.solve(make_batch(A, b, c), key=key)
         return LPSolution(x=sol.x[0], feasible=sol.feasible[0],
                           objective=sol.objective[0])
-
-    # -- introspection ----------------------------------------------------
-
-    def cache_info(self) -> dict:
-        """Distinct (shape, dtype, keyed) entries solved so far — each
-        cost exactly one compile in the underlying jit caches."""
-        return {"n_entries": len(self._shapes),
-                "shapes": sorted(str(k) for k in self._shapes)}
 
     def __repr__(self) -> str:
         return f"Solver({self.spec!r})"

@@ -2,6 +2,8 @@
 jit/vmap composability, backend resolution, and cross-backend
 equivalence properties (Seidel exact backends and the first-order pdhg
 backend)."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -12,6 +14,7 @@ from repro.core import (LPBatch, adversarial_lp, infeasible_lp,
                         make_batch, pack, ragged_feasible_lp,
                         random_feasible_lp, split_batch, unpack)
 from repro.solver import Solver, SolverSpec, get_solver, solve_with_spec
+from repro.solver.solver import STAGES
 
 TOL_5SIG = 5e-4  # the paper's 5-significant-figure comparison tolerance
 
@@ -166,15 +169,104 @@ def test_build_and_solve_paths_agree():
 
 
 def test_solver_shape_cache():
+    # one compile per input shape, and per calling convention
     solver = SolverSpec(backend="rgb").build()
     solver.solve(random_feasible_lp(jax.random.key(0), 8, 12))
     solver.solve(random_feasible_lp(jax.random.key(1), 8, 12))
-    assert solver.cache_info()["n_entries"] == 1
+    assert solver._jit_plain._cache_size() == 1
     solver.solve(random_feasible_lp(jax.random.key(2), 16, 12))
-    assert solver.cache_info()["n_entries"] == 2
+    assert solver._jit_plain._cache_size() == 2
     solver.solve(random_feasible_lp(jax.random.key(3), 8, 12),
                  key=jax.random.key(0))  # keyed variant is its own entry
-    assert solver.cache_info()["n_entries"] == 3
+    assert solver._jit_plain._cache_size() == 2
+    assert solver._jit_keyed._cache_size() == 1
+
+
+# -- profile names: the stages of a solve and its entry points -----------
+
+def _stages_by_instruction(text):
+    """``{instruction: stages}`` over an HLO module's text: for each
+    instruction whose ``op_name`` metadata names its source, the
+    ``solver.*`` scopes on each part of that name (XLA joins merged
+    names with ``;``).  Arguments and bitcasts run no op on the device,
+    and the computations a reduction or sort applies (``to_apply=``, and
+    what they call) carry the applied function's names alone; they are
+    left out."""
+    comps, comp = {}, None
+    for line in text.splitlines():
+        if line and not line.startswith(" "):
+            head = line.split()
+            comp = head[1] if head[0] == "ENTRY" else head[0]
+            comps[comp] = []
+        elif comp is not None:
+            comps[comp].append(line)
+    applied = set(re.findall(r"to_apply=(%[\w.\-]+)", text))
+    todo = list(applied)
+    while todo:
+        for line in comps.get(todo.pop(), []):
+            for callee in re.findall(r"calls=(%[\w.\-]+)", line):
+                if callee not in applied:
+                    applied.add(callee)
+                    todo.append(callee)
+    found = {}
+    for comp, lines in comps.items():
+        for line in lines:
+            name = re.search(r'op_name="([^"]*)"', line)
+            if (not name or comp in applied
+                    or re.search(r" (parameter|bitcast)\(", line)):
+                continue
+            found[f"{comp} {line.split(' = ')[0].strip()}"] = [
+                [seg for seg in part.split("/") if seg.startswith("solver.")]
+                for part in name.group(1).split(";")]
+    return found
+
+
+@pytest.mark.parametrize("call", ["aos", "packed", "keyed"])
+@pytest.mark.parametrize("backend", ["kernel", "rgb"])
+def test_every_op_of_a_solve_is_in_one_stage(backend, call):
+    lp = random_feasible_lp(jax.random.key(4), 16, 20)
+    solver = SolverSpec(backend=backend, interpret=True
+                        if backend == "kernel" else None).build()
+    if call == "keyed":
+        fn, args, module = solver._jit_keyed, (lp, jax.random.key(1)), \
+            "jit_solve_keyed"
+    else:
+        fn, args, module = solver._jit_plain, \
+            (pack(lp) if call == "packed" else lp,), "jit_solve"
+    text = fn.lower(*args).compile().as_text()
+    assert re.findall(r"^HloModule (\w+)", text, re.M) == [module]
+    assert "_lambda" not in text
+    stages = _stages_by_instruction(text)
+    assert stages
+    for inst, parts in stages.items():
+        assert all(p == parts[0] for p in parts), (inst, parts)
+        assert len(parts[0]) == 1 and parts[0][0] in STAGES, (inst, parts)
+    seen = {parts[0][0] for parts in stages.values()}
+    # m 20 pads to 128 lanes: the kernel path runs all four stages
+    want = set(STAGES) if backend == "kernel" else {"solver.prepare",
+                                                    "solver.kernel"}
+    assert seen == want
+
+
+def test_solve_dispatch_is_a_span_in_the_profile(tmp_path):
+    import glob
+
+    from jax.profiler import ProfileData
+    lp = random_feasible_lp(jax.random.key(5), 8, 12)
+    solver = SolverSpec(backend="rgb").build()
+    jax.block_until_ready(solver.solve(lp))
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for _ in range(2):
+            jax.block_until_ready(solver.solve(lp))
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)
+    spans = [dict(e.stats) for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:") for line in plane.lines
+             for e in line.events if e.name == "solver.solve"]
+    assert len(spans) == 2
+    assert all(int(s["batch"]) == 8 and int(s["m"]) == 12 for s in spans)
 
 
 def test_get_solver_shares_instances():
