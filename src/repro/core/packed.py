@@ -118,6 +118,21 @@ def unpack(pb: PackedLPBatch) -> LPBatch:
                    m_valid=pb.m_valid.reshape(-1).astype(jnp.int32))
 
 
+def _with_pad_b(L: jax.Array, *, axis: int, start: int) -> jax.Array:
+    """``L`` with the offset row set to ``PAD_B`` at indices >= ``start``
+    of ``axis``: the neutral ``b`` of zero-padded columns or problems.
+
+    A select, not a scatter, so XLA fuses it with the ``jnp.pad`` that
+    made ``L`` into one elementwise pass.  ``.at[...].set`` into the
+    padded buffer stays a separate dynamic-update-slice at an unaligned
+    lane offset (3.4 ms for 16384 x 4 x 128 float32 on a TPU v5e).
+    """
+    row = jax.lax.broadcasted_iota(jnp.int32, L.shape, 1)
+    idx = jax.lax.broadcasted_iota(jnp.int32, L.shape, axis)
+    return jnp.where((row == 2) & (idx >= start),
+                     jnp.asarray(PAD_B, L.dtype), L)
+
+
 def pad_packed(pb: PackedLPBatch, m_pad: int) -> PackedLPBatch:
     """Pad the constraint axis up to ``m_pad`` with neutral columns
     (a = 0, b = 1) — the packed twin of ``lp.pad_batch``."""
@@ -126,8 +141,8 @@ def pad_packed(pb: PackedLPBatch, m_pad: int) -> PackedLPBatch:
         raise ValueError(f"m_pad={m_pad} < m_pad={m}")
     if m_pad == m:
         return pb
-    L = jnp.pad(pb.L, ((0, 0), (0, 0), (0, m_pad - m)))
-    L = L.at[:, 2, m:].set(jnp.asarray(PAD_B, L.dtype))
+    L = _with_pad_b(jnp.pad(pb.L, ((0, 0), (0, 0), (0, m_pad - m))),
+                    axis=2, start=m)
     return PackedLPBatch(L=L, c=pb.c, m_valid=pb.m_valid)
 
 
@@ -140,8 +155,8 @@ def pad_packed_batch_dim(pb: PackedLPBatch, b_pad: int) -> PackedLPBatch:
     if b_pad == B:
         return pb
     pad = b_pad - B
-    L = jnp.pad(pb.L, ((0, pad), (0, 0), (0, 0)))
-    L = L.at[B:, 2, :].set(jnp.asarray(PAD_B, L.dtype))
+    L = _with_pad_b(jnp.pad(pb.L, ((0, pad), (0, 0), (0, 0))),
+                    axis=0, start=B)
     c = jnp.concatenate(
         [pb.c, jnp.broadcast_to(jnp.asarray([1.0, 0.0], pb.c.dtype),
                                 (pad, 2))])

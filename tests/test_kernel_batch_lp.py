@@ -5,13 +5,15 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st
+from _pad_reference import scatter_pad_packed, scatter_pad_packed_batch_dim
 
 from repro.core import (infeasible_lp, normalize_batch, ragged_feasible_lp,
                         random_feasible_lp, shuffle_batch)
 from repro.kernels import ops, ref
 from repro.kernels.batch_lp import (VMEM_BUDGET_BYTES, _WORDS_PER_LANE,
                                     _pick_tile)
-from repro.solver import SolverSpec, get_solver
+import repro.solver.solver as solver_mod
+from repro.solver import SolverSpec, get_solver, solve_with_spec
 
 
 def solve_rgb(lp):
@@ -66,6 +68,30 @@ def test_kernel_ragged():
     k = solve_kernel(lp)
     np.testing.assert_allclose(np.asarray(r.x), np.asarray(k.x),
                                rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("m", [64, 128])
+def test_kernel_solve_same_as_scatter_padding(m, monkeypatch):
+    # m 64 pads to 128 lanes, m 128 does not; batch 20 pads to tile 8
+    # either way.  The solve must not move when the padding is written
+    # by the pad's own pass instead of a scatter afterwards.
+    lp = shuffle_batch(jax.random.key(m + 1),
+                       ragged_feasible_lp(jax.random.key(m), 20, m))
+    spec = SolverSpec(backend="kernel", tile=8, interpret=True)
+
+    def solve():
+        return jax.jit(lambda b: solve_with_spec(spec, b))(lp)
+
+    got = solve()
+    monkeypatch.setattr(solver_mod, "pad_packed", scatter_pad_packed)
+    monkeypatch.setattr(solver_mod, "pad_packed_batch_dim",
+                        scatter_pad_packed_batch_dim)
+    want = solve()
+    assert np.asarray(want.feasible).any()
+    for field in ("x", "feasible", "objective"):
+        g = np.asarray(getattr(got, field))
+        w = np.asarray(getattr(want, field))
+        assert np.array_equal(g.view(np.uint8), w.view(np.uint8)), field
 
 
 @pytest.mark.parametrize("tile", [8, 32, 128])

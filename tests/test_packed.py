@@ -18,6 +18,8 @@ from repro.core import (LPBatch, PackedLPBatch, concat_batches,
                         split_batch, split_packed, unpack)
 from repro.kernels import ops
 
+from _pad_reference import scatter_pad_packed, scatter_pad_packed_batch_dim
+
 
 def _assert_batches_equal(a: LPBatch, b: LPBatch):
     np.testing.assert_array_equal(np.asarray(a.A), np.asarray(b.A))
@@ -85,6 +87,61 @@ def test_pad_packed_batch_dim_neutral_problems():
                           .unpack(), lp)
     with pytest.raises(ValueError):
         pad_packed_batch_dim(pack(lp), 2)
+
+
+def _signed_zero_packed(seed, batch, m):
+    """A packed batch whose rows hold -0.0 beside +0.0 and random
+    values, so a pad that touched an input word would show in its bits."""
+    rng = np.random.default_rng(seed)
+    L = rng.standard_normal((batch, 4, m)).astype(np.float32)
+    L[rng.random(L.shape) < 0.25] = -0.0
+    L[rng.random(L.shape) < 0.1] = 0.0
+    return PackedLPBatch(
+        L=jnp.asarray(L),
+        c=jnp.asarray(rng.standard_normal((batch, 2)).astype(np.float32)),
+        m_valid=jnp.asarray(rng.integers(0, m + 1, (batch, 1)),
+                            jnp.int32))
+
+
+def _assert_bits_equal(got: PackedLPBatch, want: PackedLPBatch):
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        g, w = np.asarray(g), np.asarray(w)
+        assert g.dtype == w.dtype and g.shape == w.shape
+        # raw bits: -0.0 and +0.0 differ here
+        assert np.array_equal(g.view(np.uint8), w.view(np.uint8))
+
+
+@pytest.mark.parametrize("tail", [0, 1, 7])
+@pytest.mark.parametrize("lanes", [128, 256])
+@pytest.mark.parametrize("m", [1, 63, 64, 100, 127, 128, 129])
+def test_padding_bit_identical_to_scatter_form(m, lanes, tail):
+    tile = 8
+    B = 2 * tile + tail
+    pb = _signed_zero_packed(1000 * m + 10 * lanes + tail, B, m)
+    m_pad = -(-m // lanes) * lanes
+    b_pad = -(-B // tile) * tile
+    lane_padded = pad_packed(pb, m_pad)
+    _assert_bits_equal(lane_padded, scatter_pad_packed(pb, m_pad))
+    _assert_bits_equal(
+        pad_packed_batch_dim(lane_padded, b_pad),
+        scatter_pad_packed_batch_dim(lane_padded, b_pad))
+    # as the solver runs it: both pads in one jitted program
+    both = jax.jit(lambda p: pad_packed_batch_dim(pad_packed(p, m_pad),
+                                                  b_pad))
+    _assert_bits_equal(both(pb), scatter_pad_packed_batch_dim(
+        scatter_pad_packed(pb, m_pad), b_pad))
+
+
+def test_concat_packed_bit_identical_to_scatter_form():
+    members = [_signed_zero_packed(s, b, m)
+               for s, (b, m) in enumerate([(3, 5), (2, 64), (4, 130),
+                                           (1, 128)])]
+    want = [scatter_pad_packed(pb, 130) for pb in members]
+    _assert_bits_equal(concat_packed(members), PackedLPBatch(
+        L=jnp.concatenate([pb.L for pb in want]),
+        c=jnp.concatenate([pb.c for pb in want]),
+        m_valid=jnp.concatenate([pb.m_valid for pb in want])))
 
 
 def test_concat_split_packed_roundtrip():

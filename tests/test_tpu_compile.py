@@ -20,11 +20,12 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import LPBatch
 from repro.kernels.batch_lp import _pick_tile, rgb_pallas
 from repro.serve_lp.buckets import ExecSpec
 from repro.serve_lp.mesh_layout import plan_layout
 from repro.serve_lp.sharding import _make_solve, group_launch
-from repro.solver import SolverSpec
+from repro.solver import SolverSpec, solve_with_spec
 from repro.solver.spec import DEFAULT_M
 from repro.tune import TuningTable, use_table
 
@@ -110,3 +111,26 @@ def test_served_executable_compiles_on_four_chips(topo):
         text = fn.lower(*_shapes(g.rows, SERVED_M, placement)) \
             .compile().as_text()
         assert KERNEL.search(text)
+
+
+@pytest.mark.parametrize("batch,m", [(BATCH, 64), (16100, 1024)])
+def test_solve_pads_without_a_scatter(one_chip, batch, m):
+    # m 64 pads to 128 lanes, batch 16100 to the tile: the neutral
+    # offsets are written in the pad's own pass, not by a
+    # dynamic-update-slice into the padded buffer afterwards (the slow,
+    # unaligned path on the chip).
+    def arg(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    lp = LPBatch(A=arg(batch, m, 2), b=arg(batch, m), c=arg(batch, 2),
+                 m_valid=arg(batch, dtype=jnp.int32))
+    with use_table(TuningTable()):
+        spec = SolverSpec(backend="kernel", interpret=False)
+        text = jax.jit(functools.partial(solve_with_spec, spec)) \
+            .lower(lp).compile().as_text()
+    assert KERNEL.search(text)
+    pad_ops = [line for line in text.splitlines()
+               if "solver.pad" in line and " = " in line]
+    assert pad_ops
+    assert not [line for line in pad_ops
+                if "dynamic-update-slice(" in line]
