@@ -179,8 +179,10 @@ class Tracer:
                  annotate_device: bool = False):
         self.enabled = bool(enabled)
         self.buffer = SpanBuffer(capacity)
-        # Opt-in jax.profiler.TraceAnnotation around dispatches, so
-        # device-profiler traces line up with host spans by name.
+        # Opt-in jax.profiler.TraceAnnotation around each mesh launch
+        # group's dispatch, so device-profiler traces line up with host
+        # spans by name (every flush dispatch has its own
+        # ``serve.dispatch`` annotation regardless).
         self.annotate_device = bool(annotate_device)
         self.spans_started = 0
         self.spans_recorded = 0

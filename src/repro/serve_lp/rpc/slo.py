@@ -9,8 +9,10 @@ A request's worst-case latency through the scheduler decomposes as::
 The tuning table (:mod:`repro.tune`) already records the measured
 µs/LP for every (backend, dtype, m-bucket, batch-bucket) shape class —
 that is exactly an estimate of flush service time:
-``est_flush_s(b) = us_per_lp * b_pad / n_devices``.  Given a stated p99
-target, the controller solves the decomposition per m-bucket:
+``est_flush_s(b) = us_per_lp * b_pad / used_devices``, with ``b_pad``
+and the devices used taken from the scheduler's own flush pin
+(:func:`flush_layout`).  Given a stated p99 target, the controller
+solves the decomposition per m-bucket:
 
 * cap ``max_batch`` so one flush's service time stays within
   ``service_fraction`` of the target (big-m buckets batch less);
@@ -36,7 +38,7 @@ import dataclasses
 import threading
 from typing import Dict, Optional
 
-from repro.serve_lp.buckets import bucket_batch
+from repro.serve_lp.mesh_layout import MeshLayout, plan_layout
 
 # Never plan a wait below this: a sub-millisecond timer burns a CPU on
 # tick overhead for no batching benefit.
@@ -45,6 +47,15 @@ MIN_WAIT_S = 1e-3
 # Never spend more than half the p99 target waiting: the other half
 # must cover the two flush service times in the decomposition.
 MAX_WAIT_FRACTION = 0.5
+
+
+def flush_layout(scheduler, bm: int, batch: int) -> MeshLayout:
+    """The layout a flush of ``batch`` requests in bucket ``bm`` runs
+    with: the scheduler's own pin and padded size
+    (:meth:`~repro.serve_lp.scheduler.BatchScheduler.flush_shape`), dealt
+    as the mesh planner deals it."""
+    spec, b_pad = scheduler.flush_shape(bm, batch)
+    return plan_layout(b_pad, spec.tile, scheduler.n_devices)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -145,30 +156,26 @@ class SLOController:
         return plan
 
     def _derive(self, scheduler, bm: int) -> Optional[BucketPlan]:
-        spec = scheduler.spec.resolve_for_shape(bm, scheduler.max_batch)
+        spec, _ = scheduler.flush_shape(bm, scheduler.max_batch)
         us = self._measured_us_per_lp(spec, bm, scheduler.max_batch)
         if us is None:
             return None
-        n_dev = max(1, scheduler.n_devices)
-        tile = spec.tile or 1
-        unit = scheduler._unit_for_tile(tile)
 
-        def est_flush_s(batch: int) -> float:
-            # One flush solves b_pad (batch rounded up the padding
-            # ladder) problems split across the devices the layout
-            # actually uses — under mesh sharding an underfull flush
+        def est_flush_s(m: int, us_lp: float, batch: int) -> float:
+            # One flush solves b_pad problems split across the devices
+            # its layout uses — under mesh sharding an underfull flush
             # occupies fewer than n_dev devices, so its service time
             # does not shrink with devices it never touched.
-            b_pad = bucket_batch(batch, unit)
-            used = max(1, min(n_dev, -(-b_pad // tile)))
-            return us * b_pad * 1e-6 / used
+            layout = flush_layout(scheduler, m, batch)
+            return us_lp * layout.b_pad * 1e-6 / layout.used_devices
 
         target = self.target_p99_s
         max_batch = scheduler.max_batch
         while (max_batch > self.min_batch
-               and est_flush_s(max_batch) > self.service_fraction * target):
+               and est_flush_s(bm, us, max_batch)
+               > self.service_fraction * target):
             max_batch = max(self.min_batch, max_batch // 2)
-        est = est_flush_s(max_batch)
+        est = est_flush_s(bm, us, max_batch)
         wait = target - 2.0 * est
         wait = min(max(wait, MIN_WAIT_S), MAX_WAIT_FRACTION * target)
         # Fused-flush policy: fusing solves this bucket at a larger
@@ -177,15 +184,11 @@ class SLOController:
         # bucket out of fused units; an unmeasured rung stays fusable
         # (the scheduler's fuse_max_m_ratio still bounds the blowup).
         allow_fuse = True
-        spec2 = scheduler.spec.resolve_for_shape(2 * bm,
-                                                 scheduler.max_batch)
+        spec2, _ = scheduler.flush_shape(2 * bm, scheduler.max_batch)
         us2 = self._measured_us_per_lp(spec2, 2 * bm, scheduler.max_batch)
         if us2 is not None:
-            tile2 = spec2.tile or 1
-            b2 = bucket_batch(max_batch, scheduler._unit_for_tile(tile2))
-            used2 = max(1, min(n_dev, -(-b2 // tile2)))
-            est2 = us2 * b2 * 1e-6 / used2
-            allow_fuse = est2 <= self.service_fraction * target
+            allow_fuse = (est_flush_s(2 * bm, us2, max_batch)
+                          <= self.service_fraction * target)
         return BucketPlan(bucket_m=bm, max_batch=max_batch,
                           max_wait_s=wait, est_flush_s=est,
                           source="measured", allow_fuse=allow_fuse)

@@ -64,10 +64,9 @@ failures land on the flush's futures and in the
 Two per-flush costs are engineered away:
 
 * *launch geometry* — specs with unset ``tile``/``chunk`` are pinned
-  **per bucket shape** via
-  :meth:`~repro.solver.SolverSpec.resolve_for_shape` (explicit >
-  measured tuning table > heuristic), so each bucket's executable runs
-  the geometry measured best for its shape class;
+  **per bucket shape** by :meth:`BatchScheduler.flush_shape` (explicit
+  > measured tuning table > heuristic), so each bucket's executable
+  runs the geometry measured best for its shape class;
 * *host allocation* — the packed flush buffers come from a per-bucket
   :class:`_FlushBufferPool` and are reused across flushes (steady-state
   traffic on a stable bucket performs zero buffer allocations; the pool
@@ -283,8 +282,8 @@ class BatchScheduler:
         ladder depends on the backend, so auto cannot stay
         shape-dependent here — pass an explicit backend to choose);
         ``tile=None``/``chunk=None`` are pinned per bucket shape at
-        flush time (measured tuning table first, then the serving
-        default tile of 32).
+        flush time (:meth:`flush_shape`: measured tuning table first,
+        then the heuristic).
     method, tile, chunk, M, normalize, interpret:
         deprecated flag-bag alternative to ``spec`` (mapped onto an
         equivalent SolverSpec; passing both is an error).
@@ -608,12 +607,20 @@ class BatchScheduler:
             "metrics": self.metrics.snapshot(self.cache.stats()),
         }
 
-    def _pin_for_bucket(self, bm: int, batch: int) -> SolverSpec:
-        """The fully shape-resolved spec one bucket's flush runs with:
-        explicit spec values win, then the measured tuning table at
-        this bucket's shape class, then the defaults (the dense
-        heuristic tile doubles as the historical serving default)."""
-        return self.spec.resolve_for_shape(bm, batch)
+    def flush_shape(self, bm: int, batch: int) -> Tuple[SolverSpec, int]:
+        """The fully shape-resolved spec and the padded size ``b_pad`` a
+        flush of ``batch`` requests in bucket ``bm`` runs with: explicit
+        spec values win, then the measured tuning table at this bucket's
+        shape class, then the defaults.  The SLO controller predicts
+        flush cost from this same method.
+
+        The tile is pinned on the whole flush, not on each device's
+        share: spreading a flush over more devices evens their rows but
+        costs host time per device in dispatch and completion, which a
+        host-bound service pays in throughput (measured on a four-chip
+        v5e host, PERF.md)."""
+        pinned = self.spec.resolve_for_shape(bm, batch)
+        return pinned, bucket_batch(batch, self._unit_for_tile(pinned.tile))
 
     # -- submission ------------------------------------------------------
 
@@ -975,8 +982,7 @@ class BatchScheduler:
         b = PAD_B, c = (1, 0), m_valid = 0 — no AoS intermediate, no
         device-side re-stack) and resolve the executable."""
         B = len(reqs)
-        pinned = self._pin_for_bucket(bm, B)
-        b_pad = bucket_batch(B, self._unit_for_tile(pinned.tile))
+        pinned, b_pad = self.flush_shape(bm, B)
         spec = ExecSpec(bucket_m=bm, b_pad=b_pad, solver=pinned,
                         n_devices=len(self._devices),
                         sharding=self.sharding)
@@ -1051,11 +1057,15 @@ class BatchScheduler:
                 self._inflight += 1
                 unit.counted = True
         L, c, mv = unit.bufs
+        layout = getattr(unit.exe, "layout", None)
+        chips = (layout.used_devices if layout is not None
+                 else len(self._devices))
         try:
-            if tracer.annotate_device:
-                with jax.profiler.TraceAnnotation(unit.name):
-                    unit.handle = unit.exe.dispatch(L, c, mv)
-            else:
+            # One profiler span per flush, from the call until the
+            # asynchronous dispatch returns (backpressure excluded).
+            with jax.profiler.TraceAnnotation(
+                    "serve.dispatch", flush=unit.name,
+                    bucket_m=unit.bucket_m, chips=chips):
                 unit.handle = unit.exe.dispatch(L, c, mv)
         except Exception:
             self._release_slot(unit)
