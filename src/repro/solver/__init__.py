@@ -17,6 +17,10 @@ One operation, many LPs, every backend::
              for b in ("naive", "rgb", "kernel", "pdhg")]
     sols = [s.build().solve(batch) for s in sweep]
 
+    # the kernel's work per LP (Seidel re-solves), on request only:
+    sol, stats = solve_kernel_with_stats(SolverSpec(backend="kernel"),
+                                         batch)
+
 :class:`SolverSpec` is frozen and hashable — use it directly as a
 static ``jax.jit`` argument or as an executable-cache key (the serving
 layer's ``ExecSpec`` embeds one).
@@ -33,11 +37,12 @@ input shape with the precedence *explicit > measured tuning table >
 heuristic* (see :mod:`repro.tune` and
 :meth:`SolverSpec.resolve_for_shape`).
 """
-from repro.solver.solver import Solver, solve_with_spec
+from repro.solver.solver import (KernelStats, Solver,
+                                 solve_kernel_with_stats, solve_with_spec)
 from repro.solver.spec import (BACKENDS, DEFAULT_M, SolverSpec,
                                get_solver)
 
 __all__ = [
-    "BACKENDS", "DEFAULT_M", "Solver", "SolverSpec", "get_solver",
-    "solve_with_spec",
+    "BACKENDS", "DEFAULT_M", "KernelStats", "Solver", "SolverSpec",
+    "get_solver", "solve_kernel_with_stats", "solve_with_spec",
 ]

@@ -37,7 +37,8 @@ then the static heuristics.
 """
 from __future__ import annotations
 
-from typing import Union
+import dataclasses
+from typing import Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -72,9 +73,33 @@ def solve_with_spec(spec: SolverSpec, batch: AnyLPBatch,
     ``key=None`` the batch is shuffled iff ``spec.shuffle`` (keyed by
     ``spec.seed``).
     """
+    return _solve(spec, batch, key)
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class KernelStats:
+    """Per-problem work of one kernel solve."""
+
+    resolves: jax.Array   # (B,) int32 violations re-solved (Seidel steps)
+
+
+def solve_kernel_with_stats(spec: SolverSpec, batch: AnyLPBatch, key=None
+                            ) -> Tuple[LPSolution, KernelStats]:
+    """Like :func:`solve_with_spec` on the kernel backend, but also
+    returns each problem's re-solve count.  A tile of the kernel loops
+    over its largest count + 1 scans.  ``spec`` must resolve to
+    ``backend="kernel"`` for the batch's shape."""
+    return _solve(spec, batch, key, stats=True)
+
+
+def _solve(spec: SolverSpec, batch: AnyLPBatch, key, stats: bool = False):
     is_packed = isinstance(batch, PackedLPBatch)
     m = batch.m_pad if is_packed else batch.m
     spec = spec.resolve_for_shape(m, batch.batch)
+    if stats and spec.backend != "kernel":
+        raise ValueError("kernel stats need backend='kernel', not "
+                         f"{spec.backend!r}")
     with jax.named_scope(PREPARE):
         if key is None and spec.shuffle:
             key = jax.random.key(spec.seed)
@@ -82,7 +107,8 @@ def solve_with_spec(spec: SolverSpec, batch: AnyLPBatch,
         if spec.backend == "kernel" and not is_packed:
             batch = pack(batch)
     if spec.backend == "kernel":
-        return _solve_kernel(spec, batch)
+        sol, resolves = _solve_kernel(spec, batch)
+        return (sol, KernelStats(resolves=resolves)) if stats else sol
     with jax.named_scope(KERNEL):
         return _solve_dense(spec, batch)
 
@@ -122,7 +148,8 @@ def _solve_dense(spec: SolverSpec, batch: AnyLPBatch) -> LPSolution:
         chunk=spec.chunk or 0)
 
 
-def _solve_kernel(spec: SolverSpec, pb: PackedLPBatch) -> LPSolution:
+def _solve_kernel(spec: SolverSpec, pb: PackedLPBatch
+                  ) -> Tuple[LPSolution, jax.Array]:
     # Deferred import: kernels.ops wraps this module for its public
     # compatibility surface, so the dependency must point one way only.
     from repro.kernels.batch_lp import LANE, _pick_tile, rgb_pallas
@@ -134,16 +161,17 @@ def _solve_kernel(spec: SolverSpec, pb: PackedLPBatch) -> LPSolution:
                                        itemsize=pb.L.dtype.itemsize)
         run = pad_packed_batch_dim(pb, -(-B // tile) * tile)
     with jax.named_scope(KERNEL):
-        x, feas = rgb_pallas(run.L, run.c, run.m_valid, M=spec.M,
-                             tile=tile, chunk=spec.chunk,
-                             interpret=spec.interpret)
+        x, feas, resolves = rgb_pallas(run.L, run.c, run.m_valid,
+                                       M=spec.M, tile=tile,
+                                       chunk=spec.chunk,
+                                       interpret=spec.interpret)
     with jax.named_scope(FINISH):
         x, feas = x[:B], feas[:B, 0]
         return LPSolution(
             x=x,
             feasible=feas.astype(bool),
             objective=jnp.einsum("bd,bd->b", pb.c.astype(x.dtype), x),
-        )
+        ), resolves[:B, 0]
 
 
 class Solver:

@@ -25,7 +25,8 @@ from repro.kernels.batch_lp import _pick_tile, rgb_pallas
 from repro.serve_lp.buckets import ExecSpec
 from repro.serve_lp.mesh_layout import plan_layout
 from repro.serve_lp.sharding import _make_solve, group_launch
-from repro.solver import SolverSpec, solve_with_spec
+from repro.solver import (SolverSpec, solve_kernel_with_stats,
+                          solve_with_spec)
 from repro.solver.spec import DEFAULT_M
 from repro.tune import TuningTable, use_table
 
@@ -65,13 +66,36 @@ def _kernel_text(sharding, m_pad, chunk):
     return fn.lower(*_shapes(rows, m_pad, sharding)).compile().as_text()
 
 
-@pytest.mark.parametrize("m_pad", [128, 1024, 8192])
+@pytest.mark.parametrize("m_pad", [128, 1024, 2048, 4096, 8192])
 def test_kernel_compiles_dense(one_chip, m_pad):
     assert KERNEL.search(_kernel_text(one_chip, m_pad, 0))
 
 
 def test_kernel_compiles_chunked(one_chip):
     assert KERNEL.search(_kernel_text(one_chip, 8192, 128))
+
+
+@pytest.mark.parametrize("m_pad", [1024, 2048])
+def test_kernel_compiles_chunked_at(one_chip, m_pad):
+    assert KERNEL.search(_kernel_text(one_chip, m_pad, 128))
+
+
+def test_kernel_stats_entry_compiles(one_chip):
+    # The opt-in stats path runs the same kernel and returns its
+    # per-LP re-solve count beside the solution.
+    def arg(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    lp = LPBatch(A=arg(BATCH, 1024, 2), b=arg(BATCH, 1024),
+                 c=arg(BATCH, 2), m_valid=arg(BATCH, dtype=jnp.int32))
+    with use_table(TuningTable()):
+        spec = SolverSpec(backend="kernel", interpret=False)
+        lowered = jax.jit(functools.partial(solve_kernel_with_stats, spec)) \
+            .lower(lp)
+    assert KERNEL.search(lowered.compile().as_text())
+    _, stats = lowered.out_info
+    assert stats.resolves.shape == (BATCH,)
+    assert stats.resolves.dtype == jnp.int32
 
 
 @pytest.mark.parametrize("chunk", [0, 128])
