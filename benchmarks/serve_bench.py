@@ -4,11 +4,10 @@ Emits one CSV row per traffic profile: us_per_call is mean end-to-end
 request latency; derived packs throughput / p99 / padding / cache-hit
 numbers.
 
-The ``serve_shard_*`` profiles A/B the mesh (shard_map, uneven shards,
-cross-bucket fusing) and legacy pmap flush paths on an
-underfull-heterogeneous burst, and additionally print one ``JSON``
-line each with launch counts, pad-waste fractions and the per-device
-row totals.  Run under
+The ``serve_shard_mesh`` profile runs the mesh flush path (shard_map,
+uneven shards, cross-bucket fusing) on an underfull-heterogeneous
+burst and additionally prints one ``JSON`` line with launch counts,
+pad-waste fractions and the per-device row totals.  Run under
 ``XLA_FLAGS=--xla_force_host_platform_device_count=4`` to see the
 multi-device layouts on a CPU host.
 
@@ -27,31 +26,25 @@ from repro.serve_lp.bench import (BenchConfig, run_rpc_traffic,
                                   run_traffic, smoke_config)
 
 
-def _shard_profile(sharding: str) -> BenchConfig:
+def _shard_profile() -> BenchConfig:
     """Underfull-heterogeneous burst: requests spread over the full
     m-bucket ladder, so per-bucket occupancy stays well below
     max_batch and the fused/uneven machinery has real work to do."""
     cfg = BenchConfig(requests=240, rate=2000.0, m_min=8, m_max=1024,
                       max_batch=32, max_wait_s=0.005, check=8)
     cfg.open_loop = True
-    cfg.sharding = sharding
     return cfg
 
 
 def run(full: bool = False) -> None:
     profiles = {"serve_smoke": smoke_config()}
-    # Saturating burst through the pipelined loop vs the stop-and-go
-    # loop: the A/B that shows what overlapping assembly with in-flight
-    # solves buys (inflight/overlap/idle come from the new gauges).
+    # Saturating burst through the pipelined loop: what overlapping
+    # assembly with in-flight solves buys (inflight/overlap/idle come
+    # from the gauges).
     burst = smoke_config()
     burst.open_loop = True
     burst.trace = True
     profiles["serve_burst_pipelined"] = burst
-    stopgo = smoke_config()
-    stopgo.open_loop = True
-    stopgo.pipeline = False
-    stopgo.trace = True
-    profiles["serve_burst_stopgo"] = stopgo
     if full:
         profiles["serve_open_loop"] = BenchConfig(
             requests=2000, rate=5000.0, m_max=1024, max_batch=128,
@@ -59,19 +52,14 @@ def run(full: bool = False) -> None:
         profiles["serve_kernel"] = BenchConfig(
             requests=256, rate=2000.0, m_max=256, max_batch=64,
             method="kernel", check=4)
-    # Mesh vs pmap flush-path A/B (same traffic, same seed): the mesh
-    # path fuses underfull buckets into shared launches over only the
-    # devices it needs, so it should show strictly fewer launches and
-    # at-least-pmap throughput.
-    profiles["serve_shard_mesh"] = _shard_profile("mesh")
-    profiles["serve_shard_pmap"] = _shard_profile("pmap")
-    shard_rows = {}
+    # The mesh path fuses underfull buckets into shared launches over
+    # only the devices it needs.
+    profiles["serve_shard_mesh"] = _shard_profile()
     for name, cfg in profiles.items():
         snap, _ = run_traffic(cfg, quiet=True)
-        if name.startswith("serve_shard_"):
-            row = {
+        if name == "serve_shard_mesh":
+            print("JSON " + json.dumps({
                 "profile": name,
-                "sharding": cfg.sharding,
                 "throughput_lps": round(snap["throughput_lps"], 1),
                 "launches": snap["launches_total"],
                 "flushes": snap["n_flushes"],
@@ -81,9 +69,7 @@ def run(full: bool = False) -> None:
                     snap["padding_waste_problems"], 4),
                 "pad_waste_cells": round(snap["padding_waste_cells"], 4),
                 "rows_per_device": snap["rows_per_device"],
-            }
-            shard_rows[cfg.sharding] = row
-            print("JSON " + json.dumps(row), flush=True)
+            }), flush=True)
         if "device_idle_frac" in snap:
             # Measured from device.solve spans (traced profile) —
             # supersedes the host-side estimate.
@@ -109,12 +95,6 @@ def run(full: bool = False) -> None:
              + idle +
              f"|launches={snap['launches_total']}"
              f"|fused={snap['fused_flushes']}")
-    if len(shard_rows) == 2:
-        mesh, pmap = shard_rows["mesh"], shard_rows["pmap"]
-        print(f"[serve_bench] shard A/B: mesh {mesh['launches']} "
-              f"launches @ {mesh['throughput_lps']:.1f} LPs/s vs pmap "
-              f"{pmap['launches']} launches @ "
-              f"{pmap['throughput_lps']:.1f} LPs/s", flush=True)
     # Same smoke traffic through the HTTP front end: what the network
     # layer (parse + admission + loop hop) adds over in-process submit,
     # plus the overload-phase shed rate.

@@ -12,16 +12,14 @@ one is visible) and scatters results to the futures in submission order.
         -> mesh_layout (MeshLayout planner: uneven per-device shards,
            grouped launches, planner-owned padding)
         -> sharding (dispatch/complete Executables; shard_map over the
-           planned mesh, single-device jit fallback, legacy pmap
-           escape hatch)
+           planned mesh, plain jit for a one-device launch group)
         -> futures (per-request LPResult)
 
-The serve loop is pipelined by default: flush dispatch is asynchronous
-(device handles, no host sync) and a completion worker scatters
-results, so the host assembles the next super-batch while the device
-solves the current one; ``BatchScheduler(..., pipeline=False)``
-restores the stop-and-go loop and ``max_inflight`` bounds the
-dispatch depth (backpressure).
+The serve loop is pipelined: flush dispatch is asynchronous (device
+handles, no host sync) and a completion worker scatters results, so
+the host assembles the next super-batch while the device solves the
+current one; ``max_inflight`` bounds the dispatch depth
+(backpressure).
 
 Use :class:`BatchScheduler` when requests arrive one at a time (serving,
 simulation agents, RPC handlers); build a
@@ -30,9 +28,8 @@ already hold one uniform batch.  The scheduler takes the same spec —
 ``BatchScheduler(SolverSpec(...))`` — and embeds it in every flush's
 :class:`ExecSpec` cache key.
 """
-from repro.serve_lp.buckets import (SHARDING_MODES, ExecSpec,
-                                    ExecutableCache, bucket_batch,
-                                    bucket_m, shape_ladder)
+from repro.serve_lp.buckets import (ExecSpec, ExecutableCache,
+                                    bucket_batch, bucket_m, shape_ladder)
 from repro.serve_lp.mesh_layout import (LaunchGroup, MeshLayout, make_mesh,
                                         plan_layout)
 from repro.serve_lp.metrics import ServeMetrics
@@ -43,8 +40,7 @@ from repro.solver import SolverSpec
 
 __all__ = [
     "BatchScheduler", "Executable", "ExecSpec", "ExecutableCache",
-    "LPResult", "LaunchGroup", "MeshLayout", "SHARDING_MODES",
-    "ServeMetrics", "SolverSpec", "as_executable", "bucket_batch",
+    "LPResult", "LaunchGroup", "MeshLayout", "ServeMetrics", "SolverSpec", "as_executable", "bucket_batch",
     "bucket_m", "build_executable", "make_mesh", "plan_layout",
     "shape_ladder",
 ]

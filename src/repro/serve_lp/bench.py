@@ -9,17 +9,14 @@ padding waste, executable-cache hit rate and the pipeline gauges
 (in-flight depth, overlapped dispatches, device-idle estimate).
 
 ``--open-loop`` removes the rate throttle entirely (saturating burst):
-submission always outruns the device, so with pipelining enabled the
-scheduler demonstrably keeps >= 2 flushes in flight while the host
-assembles the next one — ``--assert-overlap`` turns that claim into a
-hard check (used by CI).  ``--no-pipeline`` runs the same traffic
-through the stop-and-go loop for an A/B of the overlap win.
+submission always outruns the device, so the scheduler demonstrably
+keeps >= 2 flushes in flight while the host assembles the next one —
+``--assert-overlap`` turns that claim into a hard check (used by CI).
 
-``--sharding {mesh,pmap}`` picks the flush executable path (mesh is the
-default: shard_map launches with uneven per-device shards and
-cross-bucket fusing; pmap is the legacy escape hatch), and
-``--assert-fused`` turns "underfull buckets actually fused into shared
-launches" into a hard check (used by CI).
+Flushes run through the mesh path (shard_map launches with uneven
+per-device shards and cross-bucket fusing); ``--assert-fused`` turns
+"underfull buckets actually fused into shared launches" into a hard
+check (used by CI).
 
 ``--trace-out trace.json`` runs the traffic under a ``repro.obs``
 tracer and writes the span ring as Chrome ``trace_event`` JSON
@@ -75,11 +72,9 @@ class BenchConfig:
     check: int = 8                # requests re-solved directly, 0 = off
     warmup: bool = True           # pre-compile executables, reset counters
     interpret: Optional[bool] = None
-    pipeline: bool = True         # overlap assembly with in-flight solves
     max_inflight: int = 2         # dispatch backpressure bound
     open_loop: bool = False       # saturating burst: ignore `rate`
     assert_overlap: bool = False  # require >=2 flushes seen in flight
-    sharding: str = "mesh"        # flush executable path: mesh | pmap
     assert_fused: bool = False    # require >=1 cross-bucket fused flush
     # --rpc mode: drive the HTTP front end instead of in-process submit
     rpc: bool = False
@@ -198,9 +193,7 @@ def run_traffic(cfg: BenchConfig, *, quiet: bool = False
                         capacity=max(16384, 8 * cfg.requests))
     sched = BatchScheduler(spec, max_batch=cfg.max_batch,
                            max_wait_s=cfg.max_wait_s,
-                           pipeline=cfg.pipeline,
                            max_inflight=cfg.max_inflight,
-                           sharding=cfg.sharding,
                            tracer=tracer)
     if cfg.warmup:
         _warmup(cfg, sched, quiet)
@@ -240,14 +233,12 @@ def run_traffic(cfg: BenchConfig, *, quiet: bool = False
             "not free")
     if not quiet:
         print(f"[serve_lp.bench] {cfg.requests} requests "
-              f"({snap['n_feasible']} feasible) wall={wall:.2f}s "
-              f"pipeline={'on' if cfg.pipeline else 'off'}")
+              f"({snap['n_feasible']} feasible) wall={wall:.2f}s")
         print(sched.metrics.format_report(sched.cache.stats()))
         if cfg.check:
             print(f"[serve_lp.bench] check ok: {cfg.check} requests "
                   "match a direct solver-spec solve")
     if cfg.assert_overlap:
-        assert cfg.pipeline, "--assert-overlap needs pipelining enabled"
         assert snap["inflight_max"] >= 2, (
             "pipelined serve loop never had 2 flushes in flight "
             f"(inflight_max={snap['inflight_max']}); assembly did not "
@@ -260,7 +251,6 @@ def run_traffic(cfg: BenchConfig, *, quiet: bool = False
                   f"{snap['overlapped_dispatches']} overlapped "
                   "dispatches")
     if cfg.assert_fused:
-        assert cfg.sharding == "mesh", "--assert-fused needs mesh sharding"
         assert snap["fused_flushes"] >= 1, (
             "no flush ever fused multiple buckets "
             f"(fused_flushes={snap['fused_flushes']}); underfull "
@@ -385,7 +375,7 @@ def run_rpc_traffic(cfg: BenchConfig, *, quiet: bool = False) -> Dict:
                       interpret=cfg.interpret)
     frontend = make_frontend(
         spec, max_batch=cfg.max_batch, max_wait_s=cfg.max_wait_s,
-        max_inflight=cfg.max_inflight, pipeline=cfg.pipeline,
+        max_inflight=cfg.max_inflight,
         policy=AdmissionPolicy(
             m_max=max(cfg.m_max, 8), batch_max=max(4 * cfg.max_batch, 256),
             max_pending=1024, max_queue_age_s=0.5),
@@ -601,22 +591,15 @@ def main(argv=None) -> None:
     ap.add_argument("--check", type=int, default=8)
     ap.add_argument("--no-warmup", action="store_true",
                     help="skip executable pre-compilation")
-    ap.add_argument("--no-pipeline", action="store_true",
-                    help="stop-and-go serve loop (A/B the overlap win)")
     ap.add_argument("--max-inflight", type=int, default=2,
-                    help="dispatch backpressure bound (pipelined mode)")
+                    help="dispatch backpressure bound")
     ap.add_argument("--open-loop", action="store_true",
                     help="saturating burst: submit with no rate throttle")
     ap.add_argument("--assert-overlap", action="store_true",
                     help="fail unless >=2 flushes were in flight at once")
-    ap.add_argument("--sharding", default="mesh",
-                    choices=("mesh", "pmap"),
-                    help="flush executable path: mesh (shard_map, "
-                         "uneven shards, cross-bucket fusing) or the "
-                         "legacy pmap escape hatch")
     ap.add_argument("--assert-fused", action="store_true",
                     help="fail unless >=1 flush fused multiple "
-                         "m-buckets into one launch (mesh only)")
+                         "m-buckets into one launch")
     ap.add_argument("--trace", action="store_true",
                     help="run under a repro.obs tracer (measured "
                          "device-idle numbers in the report)")
@@ -658,11 +641,9 @@ def main(argv=None) -> None:
             max_wait_s=args.max_wait_ms / 1e3, tile=args.tile,
             chunk=args.chunk, seed=args.seed, check=args.check)
     cfg.warmup = not args.no_warmup
-    cfg.pipeline = not args.no_pipeline
     cfg.max_inflight = args.max_inflight
     cfg.open_loop = args.open_loop
     cfg.assert_overlap = args.assert_overlap
-    cfg.sharding = args.sharding
     cfg.assert_fused = args.assert_fused
     cfg.trace = args.trace
     cfg.trace_out = args.trace_out

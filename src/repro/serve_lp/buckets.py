@@ -10,11 +10,9 @@ executables:
   anyway, and 8 for the dense solvers, which have no layout requirement
   and should not pad an m=8 LP 16x (doubling bounds waste at 2x and
   caps the ladder at ~log2(m_max/base) rungs);
-* the batch dimension rounds up to ``unit * 2^k`` where ``unit`` is
-  one kernel ``tile`` under mesh sharding (the MeshLayout planner owns
-  any further per-device padding) or ``tile * n_devices`` under the
-  legacy pmap path (which needs whole equal shards); doubling again
-  bounds the rung count.
+* the batch dimension rounds up to ``tile * 2^k`` — one kernel
+  ``tile`` is the unit, since the MeshLayout planner owns any further
+  per-device padding; doubling again bounds the rung count.
 
 The :class:`ExecutableCache` maps an :class:`ExecSpec` (the full shape +
 method key) to a built solver executable and counts hits/misses so the
@@ -64,29 +62,20 @@ def shape_ladder(m_max: int, *, base: int = LANE) -> List[int]:
     return out
 
 
-# Flush-sharding modes a spec (and the scheduler) may name: "mesh" is
-# the MeshLayout/shard_map planner, "pmap" the legacy even-split
-# escape hatch (one release; see serve_lp.sharding).
-SHARDING_MODES = ("mesh", "pmap")
-
-
 @dataclasses.dataclass(frozen=True)
 class ExecSpec:
     """Everything that determines a compiled solver executable: the
-    padded shapes, the device count, the sharding mode and the full
-    (resolved) :class:`~repro.solver.SolverSpec`.
+    padded shapes, the device count and the full (resolved)
+    :class:`~repro.solver.SolverSpec`.
 
     Embedding the whole solver spec in the cache key is deliberate —
     two schedulers with different specs (dtype, shuffle seed, M, ...)
-    can never alias each other's executables.  Likewise ``sharding``:
-    a mesh executable and a pmap executable for the same shapes are
-    different compiled plans and must not alias."""
+    can never alias each other's executables."""
 
     bucket_m: int      # padded constraint count (LANE multiple)
-    b_pad: int         # padded batch size (see sharding-mode rules)
+    b_pad: int         # padded batch size (any positive count)
     solver: SolverSpec
     n_devices: int = 1
-    sharding: str = "mesh"
 
     def __post_init__(self):
         if not isinstance(self.solver, SolverSpec):
@@ -98,9 +87,6 @@ class ExecSpec:
             raise ValueError(
                 "ExecSpec needs a concrete solver.tile (shards are "
                 "whole numbers of tiles)")
-        if self.sharding not in SHARDING_MODES:
-            raise ValueError(
-                f"sharding={self.sharding!r} not in {SHARDING_MODES}")
         if self.bucket_m < 1:
             raise ValueError(f"bucket_m={self.bucket_m} < 1")
         if self.b_pad < 1:
@@ -109,28 +95,6 @@ class ExecSpec:
         if self.solver.backend == "kernel" and self.bucket_m % LANE:
             raise ValueError(f"bucket_m={self.bucket_m} not a {LANE} "
                              "multiple")
-        # Only legacy pmap needs whole equal shards; the mesh planner
-        # owns padding and accepts any positive b_pad.
-        if (self.sharding == "pmap"
-                and self.b_pad % (self.solver.tile * self.n_devices)):
-            raise ValueError(
-                f"b_pad={self.b_pad} not a multiple of tile*n_devices="
-                f"{self.solver.tile * self.n_devices} (pmap needs "
-                "whole equal shards; use sharding='mesh')")
-
-    # Convenience views kept for call sites/reporting that predate the
-    # embedded spec.
-    @property
-    def method(self) -> str:
-        return self.solver.backend
-
-    @property
-    def tile(self) -> int:
-        return self.solver.tile
-
-    @property
-    def chunk(self) -> int:
-        return self.solver.chunk
 
 
 class ExecutableCache:

@@ -6,8 +6,8 @@ embarrassingly parallel across that axis, so sharding a flush is purely
 a layout question: which contiguous row range does each device own?
 This module answers it with a tiny size/stride layout algebra (in the
 CuTe spirit: a layout is shapes + strides mapping logical coordinates
-to offsets) instead of pmap's single implicit answer ("split evenly
-over all local devices").
+to offsets) instead of a single implicit answer ("split evenly over
+all local devices").
 
 :func:`plan_layout` turns ``(rows, tile, n_devices)`` into a
 :class:`MeshLayout`:
@@ -23,8 +23,8 @@ over all local devices").
   sizes form one :class:`LaunchGroup`, executed as a single
   ``shard_map`` over a contiguous sub-mesh.  The q/q+1 deal means a
   layout never needs more than two groups, so even a maximally uneven
-  flush costs at most two launches (pmap would instead *pad* to the
-  worst device).
+  flush costs at most two launches (an even split would instead *pad*
+  to the worst device).
 
 Multi-host seam
 ---------------

@@ -174,7 +174,7 @@ class ServeMetrics:
         self.n_flushes = 0
         self.flush_reasons: Dict[str, int] = {}
         # Sharding/fusing observability: total device launches (a mesh
-        # flush may group into 1-2 sub-mesh launches; pmap/jit is 1),
+        # flush may group into 1-2 sub-mesh launches),
         # fused multi-bucket flush units, how many m-buckets those
         # folded together, and packed rows dispatched per device index.
         self.launches = 0

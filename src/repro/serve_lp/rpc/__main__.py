@@ -58,7 +58,6 @@ def main(argv=None) -> None:
     ap.add_argument("--max-wait-ms", type=float, default=5.0,
                     help="scheduler-wide wait trigger")
     ap.add_argument("--max-inflight", type=int, default=2)
-    ap.add_argument("--no-pipeline", action="store_true")
     ap.add_argument("--target-p99-ms", type=float, default=None,
                     help="enable the SLO controller: derive per-bucket "
                          "max_batch/max_wait from measured flush "
@@ -119,7 +118,6 @@ def main(argv=None) -> None:
         max_batch=args.max_batch,
         max_wait_s=args.max_wait_ms / 1e3,
         max_inflight=args.max_inflight,
-        pipeline=not args.no_pipeline,
         policy=AdmissionPolicy(
             m_max=args.m_max, batch_max=args.batch_max,
             max_pending=args.max_pending,

@@ -666,7 +666,6 @@ def make_frontend(spec=None, *,
                   max_batch: int = 256,
                   max_wait_s: float = 0.005,
                   max_inflight: int = 2,
-                  pipeline: bool = True,
                   policy: Optional[AdmissionPolicy] = None,
                   quotas: Optional[QuotaManager] = None,
                   target_p99_s: Optional[float] = None,
@@ -681,7 +680,7 @@ def make_frontend(spec=None, *,
     from repro.serve_lp.scheduler import BatchScheduler
     scheduler = BatchScheduler(
         spec, max_batch=max_batch, max_wait_s=max_wait_s,
-        max_inflight=max_inflight, pipeline=pipeline, metrics=metrics,
+        max_inflight=max_inflight, metrics=metrics,
         tracer=tracer, recorder=recorder, devices=devices)
     slo = (SLOController(target_p99_s)
            if target_p99_s is not None else None)

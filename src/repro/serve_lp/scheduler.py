@@ -33,17 +33,11 @@ one blocking call —
   future in submission order.  ``_InflightFlush.done`` is the explicit
   per-unit join point; :meth:`drain` joins all of them.
 
-``pipeline=False`` restores the stop-and-go loop (the three stages run
-back-to-back on the flushing thread), which is still what you want for
-strictly step-synchronous callers that flush and immediately wait.
-
-Flushes shard over devices per the scheduler's ``sharding`` mode:
-``"mesh"`` (default) plans a
-:class:`~repro.serve_lp.mesh_layout.MeshLayout` per flush — uneven
-per-device shards, planner-owned padding (the batch ladder unit is one
-kernel ``tile``, not ``tile * n_devices``) and grouped ``shard_map``
-launches; ``"pmap"`` is the legacy even-split escape hatch.  Mesh mode
-also enables **cross-bucket fusing** (``fuse=True``): buckets whose
+Every flush shards over devices through a per-flush
+:class:`~repro.serve_lp.mesh_layout.MeshLayout` — uneven per-device
+shards, planner-owned padding (the batch ladder unit is one kernel
+``tile``, not ``tile * n_devices``) and grouped ``shard_map`` launches.
+**Cross-bucket fusing** (``fuse=True``, the default): buckets whose
 queues are individually under the size trigger but jointly fill a
 launch are drained into one *fused flush unit* — their requests packed
 into a single super-batch padded to the largest member's ``m_pad``
@@ -51,15 +45,17 @@ into a single super-batch padded to the largest member's ``m_pad``
 executables), solved in one launch, and scattered back to each
 request's own future.  Fusing fires on the submit path (joint-fill
 trigger, reason ``"fused"``), in the wait-trigger sweep, and on manual
-:meth:`flush`; the SLO controller can veto it per bucket via the
-3-tuple bucket-policy form.
+:meth:`flush`; both triggers group buckets by one rule
+(:meth:`BatchScheduler._fuse_runs`), and the SLO controller can veto
+fusing per bucket via the 3-tuple bucket-policy form.
 
 Failure discipline: a solve failure reaches every future of *its own*
 flush via ``set_exception`` and never orphans another bucket — manual
 and expired flushes isolate per-bucket errors and re-raise the first
-one only after every drained bucket has been dispatched.  Completion
-failures land on the flush's futures and in the
-``ServeMetrics`` error counters (never silently swallowed).
+assemble/dispatch error only after every drained bucket has been
+dispatched.  Failures at completion land on the flush's futures and in
+the ``ServeMetrics`` ``"solve"`` error counter (never silently
+swallowed).
 
 Two per-flush costs are engineered away:
 
@@ -89,8 +85,8 @@ from repro.kernels.batch_lp import LANE
 from repro.obs.recorder import FlightRecorder
 from repro.obs.trace import (NOOP_TRACER, TraceContext, Tracer,
                              new_trace_context)
-from repro.serve_lp.buckets import (SHARDING_MODES, ExecSpec,
-                                    ExecutableCache, bucket_batch, bucket_m)
+from repro.serve_lp.buckets import (ExecSpec, ExecutableCache,
+                                    bucket_batch, bucket_m)
 from repro.serve_lp.metrics import ServeMetrics
 from repro.serve_lp.sharding import as_executable, build_executable
 from repro.solver import SolverSpec
@@ -258,7 +254,6 @@ class _InflightFlush:
     t_dispatch: float = 0.0      # dispatch enqueued (device handed work)
     t_complete: float = 0.0      # device results materialized on host
     handle: Any = None           # in-flight device result handle
-    counted: bool = False        # holds an in-flight slot (pipelined)
     # Tracing: flush-plane spans are emitted once per flush under the
     # *primary* trace (the first member request's); membership of every
     # fused-in trace rides on the flush.assemble span's trace_ids attr.
@@ -274,42 +269,34 @@ class BatchScheduler:
     Parameters
     ----------
     spec:
-        the :class:`~repro.solver.SolverSpec` every flush solves with.
-        It becomes part of each flush's :class:`ExecSpec` cache key, so
-        two schedulers with different specs can never alias
-        executables.  ``backend="auto"``/``interpret=None`` resolve
-        against the running JAX backend at construction (the m-bucket
-        ladder depends on the backend, so auto cannot stay
-        shape-dependent here — pass an explicit backend to choose);
+        the :class:`~repro.solver.SolverSpec` every flush solves with
+        (default ``SolverSpec(backend="rgb")``).  It becomes part of
+        each flush's :class:`ExecSpec` cache key, so two schedulers
+        with different specs can never alias executables.
+        ``backend="auto"``/``interpret=None`` resolve against the
+        running JAX backend at construction (the m-bucket ladder
+        depends on the backend, so auto cannot stay shape-dependent
+        here — pass an explicit backend to choose);
         ``tile=None``/``chunk=None`` are pinned per bucket shape at
         flush time (:meth:`flush_shape`: measured tuning table first,
         then the heuristic).
-    method, tile, chunk, M, normalize, interpret:
-        deprecated flag-bag alternative to ``spec`` (mapped onto an
-        equivalent SolverSpec; passing both is an error).
     max_batch:
         size trigger — a bucket flushes as soon as it holds this many.
     max_wait_s:
         wait trigger — no request waits longer than this once the
         background thread is running.
-    pipeline:
-        overlap flush assembly with in-flight solves (default).  A
-        flush's dispatch returns without synchronizing and a completion
-        worker scatters results; ``False`` restores the stop-and-go
-        loop where each flush blocks until its results are scattered.
     max_inflight:
         backpressure bound — a new dispatch blocks while this many
-        flushes are already in flight (pipelined mode only).
+        flushes are already in flight.
     devices:
         device list to shard flushes over; default ``jax.devices()``.
-    sharding:
-        flush-sharding mode — ``"mesh"`` (MeshLayout planner +
-        shard_map; uneven shards, planner-owned padding) or ``"pmap"``
-        (legacy even-split escape hatch, kept one release).
     fuse:
-        enable cross-bucket fused flush units.  Defaults to ``True``
-        under mesh sharding and ``False`` under pmap (whose fixed
-        even-split geometry predates fused units).
+        enable cross-bucket fused flush units (default ``True``).
+    pipeline, sharding:
+        accepted only as ``True`` and ``"mesh"`` — the one serve loop
+        and the one sharding path; any other value raises
+        ``ValueError``.  They stay only until the benchmark's served
+        configurations stop passing them.
     fuse_max_m_ratio:
         never fuse buckets whose ``m_pad`` differ by more than this
         factor — fusing an m=8 bucket into an m=4096 flush would burn
@@ -331,20 +318,14 @@ class BatchScheduler:
         self,
         spec: Optional[SolverSpec] = None,
         *,
-        method: Optional[str] = None,
         max_batch: int = 256,
         max_wait_s: float = 0.005,
-        tile: Optional[int] = None,
-        chunk: Optional[int] = None,
-        M: Optional[float] = None,
-        normalize: Optional[bool] = None,
-        interpret: Optional[bool] = None,
         pipeline: bool = True,
         max_inflight: int = DEFAULT_MAX_INFLIGHT,
         devices: Optional[Sequence] = None,
         metrics: Optional[ServeMetrics] = None,
         sharding: str = "mesh",
-        fuse: Optional[bool] = None,
+        fuse: bool = True,
         fuse_max_m_ratio: float = 8.0,
         tracer: Optional[Tracer] = None,
         recorder: Optional[FlightRecorder] = None,
@@ -353,22 +334,19 @@ class BatchScheduler:
             raise ValueError(f"max_batch={max_batch} < 1")
         if max_inflight < 1:
             raise ValueError(f"max_inflight={max_inflight} < 1")
-        if sharding not in SHARDING_MODES:
+        if pipeline is not True:
             raise ValueError(
-                f"sharding={sharding!r} not in {SHARDING_MODES}")
+                f"pipeline={pipeline!r}: the pipelined loop is the only "
+                "serve loop")
+        if sharding != "mesh":
+            raise ValueError(
+                f"sharding={sharding!r}: the mesh planner is the only "
+                "sharding path")
         if fuse_max_m_ratio < 1:
             raise ValueError(
                 f"fuse_max_m_ratio={fuse_max_m_ratio} < 1")
-        legacy = {k: v for k, v in dict(
-            backend=method, tile=tile, chunk=chunk, M=M,
-            normalize=normalize, interpret=interpret).items()
-            if v is not None}
         if spec is None:
-            spec = SolverSpec(**{"backend": "rgb", **legacy})
-        elif legacy:
-            raise TypeError(
-                f"pass either spec= or legacy solver kwargs, not both "
-                f"(got {sorted(legacy)})")
+            spec = SolverSpec(backend="rgb")
         elif not isinstance(spec, SolverSpec):
             raise TypeError(f"spec must be a SolverSpec, got "
                             f"{type(spec)!r}")
@@ -395,10 +373,8 @@ class BatchScheduler:
         self._dtype = np.dtype(spec.dtype)
         self.max_batch = max_batch
         self.max_wait_s = max_wait_s
-        self.pipeline = bool(pipeline)
         self.max_inflight = max_inflight
-        self.sharding = sharding
-        self.fuse = (sharding == "mesh") if fuse is None else bool(fuse)
+        self.fuse = bool(fuse)
         self.fuse_max_m_ratio = float(fuse_max_m_ratio)
         # Only the Pallas kernel needs LANE-multiple constraint counts;
         # the dense solvers bucket on a finer ladder so tiny LPs are not
@@ -450,49 +426,19 @@ class BatchScheduler:
         # sooner).  None falls back to the scheduler-wide limits.
         self._bucket_policy: Optional[Any] = None
 
-    # Legacy attribute views (pre-SolverSpec callers/reporting).
-    @property
-    def method(self) -> str:
-        return self.spec.backend
-
-    @property
-    def tile(self) -> int:
-        return (self.spec.tile if self.spec.tile is not None
-                else DEFAULT_SERVE_TILE)
-
-    @property
-    def chunk(self) -> int:
-        return 0 if self.spec.chunk is None else self.spec.chunk
-
-    @property
-    def M(self) -> float:
-        return self.spec.M
-
-    @property
-    def normalize(self) -> bool:
-        return self.spec.normalize
-
-    @property
-    def interpret(self) -> bool:
-        return self.spec.interpret
-
     @property
     def n_devices(self) -> int:
         return len(self._devices)
 
     @property
     def batch_unit(self) -> int:
-        """Fallback flush-padding unit.  Mesh sharding pads to whole
-        kernel tiles only (the MeshLayout planner owns the per-device
-        distribution); legacy pmap needs a whole tile per device.
-        Buckets whose pinned tile differs (tuned entries) pad on their
-        own unit."""
-        return self._unit_for_tile(self.tile)
-
-    def _unit_for_tile(self, tile: int) -> int:
-        if self.sharding == "pmap":
-            return tile * len(self._devices)
-        return tile
+        """Fallback flush-padding unit: the spec's tile, or
+        :data:`DEFAULT_SERVE_TILE` when it leaves the tile unset.
+        Flushes pad to whole kernel tiles only (the MeshLayout planner
+        owns the per-device distribution); buckets whose pinned tile
+        differs (tuned entries) pad on their own unit."""
+        return (self.spec.tile if self.spec.tile is not None
+                else DEFAULT_SERVE_TILE)
 
     @property
     def inflight(self) -> int:
@@ -594,8 +540,6 @@ class BatchScheduler:
             "active_flushes": active,
             "inflight": inflight,
             "max_inflight": self.max_inflight,
-            "pipeline": self.pipeline,
-            "sharding": self.sharding,
             "fuse": self.fuse,
             "n_devices": len(self._devices),
             "buffer_pool": {
@@ -620,7 +564,7 @@ class BatchScheduler:
         host-bound service pays in throughput (measured on a four-chip
         v5e host, PERF.md)."""
         pinned = self.spec.resolve_for_shape(bm, batch)
-        return pinned, bucket_batch(batch, self._unit_for_tile(pinned.tile))
+        return pinned, bucket_batch(batch, pinned.tile)
 
     # -- submission ------------------------------------------------------
 
@@ -695,36 +639,49 @@ class BatchScheduler:
         several buckets are each under their size trigger but together
         fill a launch, pop them as one fused flush unit.
 
-        Returns the popped ``[(bucket_m, reqs), ...]`` parts, or None
-        when no fusable group of >= 2 buckets reaches ``max_batch``
-        rows.  Grouping mirrors :meth:`_plan_units`: buckets sorted by
-        ``m_pad``, split where the spread exceeds ``fuse_max_m_ratio``.
+        Returns the popped ``[(bucket_m, reqs), ...]`` parts of the
+        :meth:`_fuse_runs` run of two or more buckets with the most rows
+        (the first on a tie), or None when that run holds fewer than
+        ``max_batch`` rows.  The run is not capped at ``max_batch``: a
+        joint fill pops every bucket of its run.
         """
         total = sum(len(q) for q in self._queues.values())
         if total < self.max_batch:
             return None
-        cands = sorted(
-            ((b, q) for b, q in self._queues.items()
-             if q and self._fuse_ok(b)),
-            key=lambda t: t[0])
+        cands = [(b, q) for b, q in self._queues.items()
+                 if q and self._fuse_ok(b)]
         if len(cands) < 2:
             return None
         best: List[Tuple[int, list]] = []
         best_rows = 0
-        cur: List[Tuple[int, list]] = []
-        cur_rows = 0
-        for b, q in cands:
-            if cur and b > cur[0][0] * self.fuse_max_m_ratio:
-                cur, cur_rows = [], 0
-            cur.append((b, q))
-            cur_rows += len(q)
-            if len(cur) >= 2 and cur_rows > best_rows:
-                best, best_rows = list(cur), cur_rows
+        for run in self._fuse_runs(cands):
+            rows = sum(len(q) for _, q in run)
+            if len(run) >= 2 and rows > best_rows:
+                best, best_rows = run, rows
         if best_rows < self.max_batch:
             return None
         for b, _ in best:
             self._queues.pop(b)
         return best
+
+    def _fuse_runs(self, cands: List[Tuple[int, list]],
+                   row_cap: Optional[int] = None
+                   ) -> List[List[Tuple[int, list]]]:
+        """The one grouping rule of both fuse triggers: sort fusable
+        buckets by ``m_pad`` and split them into runs wherever a
+        bucket's ``m_pad`` exceeds the run's first times
+        ``fuse_max_m_ratio`` (pad-cell waste) or, given ``row_cap``,
+        where the run's rows would exceed the cap."""
+        runs: List[List[Tuple[int, list]]] = []
+        rows = 0
+        for bm, q in sorted(cands, key=lambda t: t[0]):
+            if (not runs or bm > runs[-1][0][0] * self.fuse_max_m_ratio
+                    or (row_cap is not None and rows + len(q) > row_cap)):
+                runs.append([])
+                rows = 0
+            runs[-1].append((bm, q))
+            rows += len(q)
+        return runs
 
     def submit_many(self, As, bs, cs, m_valid=None) -> List[Future]:
         """Row-wise submit of stacked arrays (B, m, 2)/(B, m)/(B, 2);
@@ -745,7 +702,7 @@ class BatchScheduler:
     def flush(self) -> int:
         """Drain all buckets now (manual trigger); returns LPs solved
         (dispatched — use :meth:`drain` or the futures to wait for
-        completion in pipelined mode).
+        completion).
 
         One unit's failure never orphans another's futures: every
         drained unit is dispatched regardless, each failure lands on
@@ -780,13 +737,11 @@ class BatchScheduler:
                     ) -> List[List[Tuple[int, list]]]:
         """Partition drained buckets into flush units.
 
-        With fusing off (or one bucket) every bucket is its own unit —
-        the pre-mesh behaviour.  Otherwise buckets that are underfull
-        *and* policy-fusable are sorted by ``m_pad`` and greedily
-        packed into fused units, closing a unit when the m-spread
-        would exceed ``fuse_max_m_ratio`` (pad-cell waste) or the row
-        count would exceed ``max_batch`` (keeps fused ``b_pad`` on the
-        same ladder rungs normal flushes compile)."""
+        With fusing off (or one bucket) every bucket is its own unit.
+        Otherwise full and policy-vetoed buckets stay single units and
+        the rest are grouped by :meth:`_fuse_runs` with a ``max_batch``
+        row cap (keeps fused ``b_pad`` on the same ladder rungs normal
+        flushes compile)."""
         if not self.fuse or len(drained) < 2:
             return [[(bm, q)] for bm, q in drained]
         singles: List[List[Tuple[int, list]]] = []
@@ -796,20 +751,7 @@ class BatchScheduler:
                 singles.append([(bm, q)])
             else:
                 cands.append((bm, q))
-        cands.sort(key=lambda t: t[0])
-        units: List[List[Tuple[int, list]]] = []
-        cur: List[Tuple[int, list]] = []
-        cur_rows = 0
-        for bm, q in cands:
-            if cur and (bm > cur[0][0] * self.fuse_max_m_ratio
-                        or cur_rows + len(q) > self.max_batch):
-                units.append(cur)
-                cur, cur_rows = [], 0
-            cur.append((bm, q))
-            cur_rows += len(q)
-        if cur:
-            units.append(cur)
-        return singles + units
+        return singles + self._fuse_runs(cands, row_cap=self.max_batch)
 
     def pending(self) -> int:
         with self._lock:
@@ -918,7 +860,7 @@ class BatchScheduler:
     def _solve_unit(self, parts: List[Tuple[int, List[_Pending]]], *,
                     reason: str, pre_counted: bool = False) -> None:
         """Flush one unit — one bucket, or several fused: assemble,
-        dispatch and — pipelined — hand completion to the worker.  A
+        dispatch and hand completion to the worker.  A
         fused unit solves every member's requests in a single
         super-batch padded to the largest member's ``m_pad`` (the
         per-problem results are bit-identical either way — padding
@@ -970,10 +912,6 @@ class BatchScheduler:
                 tracer.end(r.span, error=type(e).__name__)
                 r.qspan = r.span = None
             raise
-        if not self.pipeline:
-            err = self._complete_unit(unit)
-            if err is not None:
-                raise err
 
     def _assemble(self, bm: int, reqs: List[_Pending],
                   reason: str, n_buckets: int = 1) -> _InflightFlush:
@@ -984,8 +922,7 @@ class BatchScheduler:
         B = len(reqs)
         pinned, b_pad = self.flush_shape(bm, B)
         spec = ExecSpec(bucket_m=bm, b_pad=b_pad, solver=pinned,
-                        n_devices=len(self._devices),
-                        sharding=self.sharding)
+                        n_devices=len(self._devices))
         # The flush is named before any work so its queue.wait /
         # flush.* spans can carry the name from the start.
         with self._lock:
@@ -1050,12 +987,10 @@ class BatchScheduler:
                 parent_id=(unit.asm_span.span_id
                            if unit.asm_span is not None else None),
                 flush=unit.name, bucket_m=unit.bucket_m)
-        if self.pipeline:
-            with self._inflight_cv:
-                self._inflight_cv.wait_for(
-                    lambda: self._inflight < self.max_inflight)
-                self._inflight += 1
-                unit.counted = True
+        with self._inflight_cv:
+            self._inflight_cv.wait_for(
+                lambda: self._inflight < self.max_inflight)
+            self._inflight += 1
         L, c, mv = unit.bufs
         layout = getattr(unit.exe, "layout", None)
         chips = (layout.used_devices if layout is not None
@@ -1068,23 +1003,17 @@ class BatchScheduler:
                     bucket_m=unit.bucket_m, chips=chips):
                 unit.handle = unit.exe.dispatch(L, c, mv)
         except Exception:
-            self._release_slot(unit)
+            with self._inflight_cv:
+                self._inflight -= 1
+                self._inflight_cv.notify_all()
             self.buffers.release(unit.buf_key, unit.bufs)
             raise
         unit.t_dispatch = time.perf_counter()
         tracer.end(dspan, t_end=unit.t_dispatch,
                    launches=getattr(unit.exe, "n_launches", 1))
         self.metrics.record_dispatch()
-        if self.pipeline:
-            self._ensure_completer()
-            self._work_q.put(unit)
-
-    def _release_slot(self, unit: _InflightFlush) -> None:
-        if unit.counted:
-            with self._inflight_cv:
-                self._inflight -= 1
-                self._inflight_cv.notify_all()
-            unit.counted = False
+        self._ensure_completer()
+        self._work_q.put(unit)
 
     def _ensure_completer(self) -> None:
         t = self._completer
@@ -1118,13 +1047,11 @@ class BatchScheduler:
                     "completion_worker",
                     warn=f"serve_lp: completion worker error {e!r}")
 
-    def _complete_unit(self, unit: _InflightFlush
-                       ) -> Optional[BaseException]:
+    def _complete_unit(self, unit: _InflightFlush) -> None:
         """Join stage: block on the device results, return the leased
         buffers (safe only now — see :class:`_FlushBufferPool`), record
-        metrics, scatter futures.  Returns the solve error, if any,
-        instead of raising (the sync path re-raises it; the worker
-        routes it to futures + error counters)."""
+        metrics, scatter futures.  A solve error is counted as a
+        ``"solve"`` error and lands on the unit's futures."""
         err: Optional[BaseException] = None
         x = feas = None
         try:
@@ -1135,8 +1062,8 @@ class BatchScheduler:
         unit.handle = None
         # Device is synchronized (or dead): the host buffers are free.
         self.buffers.release(unit.buf_key, unit.bufs)
-        self._release_slot(unit)
         with self._inflight_cv:
+            self._inflight -= 1
             self._active -= 1
             self._inflight_cv.notify_all()
         self.metrics.record_complete()
@@ -1164,15 +1091,14 @@ class BatchScheduler:
                            flush=unit.name)
                 r.span = None
             tracer.end(sspan, error=type(err).__name__)
-            if self.pipeline:
-                self.metrics.record_error(
-                    "solve",
-                    warn=f"serve_lp: {unit.name} failed ({err!r}); its "
-                         "futures carry the exception")
+            self.metrics.record_error(
+                "solve",
+                warn=f"serve_lp: {unit.name} failed ({err!r}); its "
+                     "futures carry the exception")
             for r in unit.reqs:
                 _try_set_exception(r.future, err)
             unit.done.set()
-            return err
+            return
         B = len(unit.reqs)
         now = time.perf_counter()
         # Metrics before the scatter: a caller woken by future.result()
@@ -1222,15 +1148,14 @@ class BatchScheduler:
             r.span = None
         tracer.end(sspan)
         unit.done.set()
-        return None
 
     def _record_device_spans(self, unit: _InflightFlush,
                              parent: Optional[str]) -> None:
         """Emit per-launch-group ``device.solve`` spans for one
         completed flush: mesh executables get one span per
         :class:`~repro.serve_lp.mesh_layout.LaunchGroup` (its device
-        indices and row geometry as attrs); pmap/jit fallbacks get a
-        single span over every participating device."""
+        indices and row geometry as attrs); injected executables with
+        no layout get a single span over every participating device."""
         layout = getattr(unit.exe, "layout", None)
         groups = getattr(layout, "groups", ()) if layout is not None \
             else ()

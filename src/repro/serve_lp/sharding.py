@@ -1,21 +1,15 @@
 """Executable builder: one ExecSpec -> one dispatch/complete Executable.
 
-A spec's ``sharding`` mode picks how the flushed super-batch spreads
-over devices:
-
-* ``"mesh"`` (default) — a :class:`~repro.serve_lp.mesh_layout.MeshLayout`
-  plans per-device row counts (uneven shards allowed; unused devices
-  get zero rows) and each :class:`~repro.serve_lp.mesh_layout.LaunchGroup`
-  compiles to ``jax.jit(shard_map(solve))`` over a contiguous sub-mesh.
-  The planner owns padding: ``b_pad`` only needs to be positive — rows
-  are padded with neutral LPs up to whole kernel tiles here, never up
-  to ``tile * n_devices`` blocks, so a prime-sized flush on 4 devices
-  is legal.  A group of one device compiles to plain ``jax.jit``
-  (identical to the pre-mesh path).
-* ``"pmap"`` — the legacy path, kept as a one-release escape hatch:
-  ``jax.pmap`` splits the batch evenly over *all* devices and requires
-  ``b_pad % (tile * n_devices) == 0``.  Tests assert the two paths are
-  bit-identical; prefer ``"mesh"``.
+A :class:`~repro.serve_lp.mesh_layout.MeshLayout` plans how the
+flushed super-batch spreads over devices: per-device row counts (uneven
+shards allowed; unused devices get zero rows), and each
+:class:`~repro.serve_lp.mesh_layout.LaunchGroup` compiles to
+``jax.jit(shard_map(solve))`` over a contiguous sub-mesh.  The planner
+owns padding: ``b_pad`` only needs to be positive — rows are padded
+with neutral LPs up to whole kernel tiles here, never up to
+``tile * n_devices`` blocks, so a prime-sized flush on 4 devices is
+legal.  A group of one device compiles to plain ``jax.jit``.  Tests
+assert the result is bit-identical to one plain-jit launch.
 
 Built executables are *two-stage* so the serve loop can pipeline:
 
@@ -110,8 +104,7 @@ class Executable:
     XLA (its device buffer is reused for outputs; the *host* buffer is
     unaffected and still owned by the flush-buffer pool until the
     flush completes).  ``layout`` is the :class:`MeshLayout` the
-    executable was planned with (``None`` for legacy/injected
-    executables); ``shards``/``n_launches`` expose the per-device row
+    executable was planned with (``None`` for injected executables); ``shards``/``n_launches`` expose the per-device row
     counts and device-launch count for metrics.
     """
 
@@ -202,7 +195,7 @@ def _build_mesh_executable(spec: ExecSpec, devices, solve,
     launch per :class:`LaunchGroup` (uneven layouts need at most two).
     Each group jits over its own contiguous devices, so group launches
     land on disjoint devices and overlap."""
-    layout = plan_layout(spec.b_pad, spec.tile, len(devices))
+    layout = plan_layout(spec.b_pad, spec.solver.tile, len(devices))
     launches = [
         (g.offset, g.rows) + group_launch(
             solve, devices[g.start:g.start + g.n_devices], donate_kw)
@@ -238,40 +231,6 @@ def _build_mesh_executable(spec: ExecSpec, devices, solve,
                       donated=bool(donate_kw), layout=layout)
 
 
-def _build_pmap_executable(spec: ExecSpec, devices, solve,
-                           donate_kw) -> Executable:
-    """Legacy even-split path (``sharding="pmap"`` escape hatch)."""
-    D = len(devices)
-    if D == 1:
-        jitted = jax.jit(solve, **donate_kw)
-
-        def complete(handle):
-            x, feas = handle
-            return np.asarray(x), np.asarray(feas)
-
-        return Executable(
-            jitted, complete, donated=bool(donate_kw),
-            layout=MeshLayout(shards=(spec.b_pad,), tile=spec.tile))
-
-    pmapped = jax.pmap(solve, devices=devices, **donate_kw)
-    per = spec.b_pad // D
-
-    def shard(a):
-        return a.reshape((D, per) + a.shape[1:])
-
-    def dispatch(L, c, mv):
-        return pmapped(shard(L), shard(c), shard(mv))
-
-    def complete(handle):
-        x, feas = handle
-        return (np.asarray(x).reshape(spec.b_pad, 2),
-                np.asarray(feas).reshape(spec.b_pad))
-
-    return Executable(
-        dispatch, complete, donated=bool(donate_kw),
-        layout=MeshLayout(shards=(per,) * D, tile=spec.tile))
-
-
 def build_executable(
     spec: ExecSpec,
     devices: Optional[Sequence[jax.Device]] = None,
@@ -286,7 +245,4 @@ def build_executable(
     solve = _make_solve(spec)
     donate = all(d.platform in _DONATING_PLATFORMS for d in devices)
     donate_kw = {"donate_argnums": (0,)} if donate else {}
-
-    if spec.sharding == "pmap":
-        return _build_pmap_executable(spec, devices, solve, donate_kw)
     return _build_mesh_executable(spec, devices, solve, donate_kw)

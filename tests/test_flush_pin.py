@@ -81,16 +81,15 @@ def test_v5e_table_places_a_full_flush_on_two_devices():
             assert flush_layout(sched, bm, B).shards == shards, (bm, B)
 
 
-@pytest.mark.parametrize("sharding,unit", [("mesh", 1), ("pmap", 4)])
-def test_explicit_tile_wins_over_the_resolved_pin(sharding, unit):
-    """An explicit ``tile=`` is kept whatever the flush size; the legacy
-    pmap mode pads to ``tile * n_devices`` units."""
+def test_explicit_tile_wins_over_the_resolved_pin():
+    """An explicit ``tile=`` is kept whatever the flush size, and the
+    flush pads to whole tiles of it."""
     sched = BatchScheduler(SolverSpec(backend="kernel", tile=8),
-                           devices=jax.devices()[:1] * 4, sharding=sharding)
+                           devices=jax.devices()[:1] * 4)
     for B in (1, 5, 40, 256):
         pinned, b_pad = sched.flush_shape(1024, B)
         assert pinned.tile == 8
-        assert b_pad % (8 * unit) == 0 and b_pad >= B
+        assert b_pad % 8 == 0 and b_pad >= B
 
 
 E2E = r"""

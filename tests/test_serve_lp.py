@@ -21,6 +21,8 @@ from repro.serve_lp import (BatchScheduler, ExecSpec, ExecutableCache,
 from repro.serve_lp.bench import BenchConfig, make_request, run_traffic
 from repro.serve_lp.scheduler import _FlushBufferPool
 
+RGB8 = SolverSpec(backend="rgb", tile=8)
+
 
 def _mixed_requests(seed=0, ms=(3, 8, 37, 128, 130, 200), reps=2):
     rng = np.random.default_rng(seed)
@@ -55,10 +57,10 @@ def test_bucket_m_ladder():
         bucket_m(0)
 
 
-def test_scheduler_bucket_base_by_method():
-    assert BatchScheduler(method="rgb").bucket_base == 8
-    assert BatchScheduler(method="naive").bucket_base == 8
-    assert BatchScheduler(method="kernel").bucket_base == 128
+@pytest.mark.parametrize("backend,base", [("rgb", 8), ("naive", 8),
+                                          ("kernel", 128)])
+def test_scheduler_bucket_base_by_method(backend, base):
+    assert BatchScheduler(SolverSpec(backend=backend)).bucket_base == base
 
 
 def test_bucket_batch_ladder():
@@ -75,18 +77,12 @@ def test_exec_spec_validation():
                  solver=SolverSpec(backend="kernel", tile=32))
     ExecSpec(bucket_m=16, b_pad=32, solver=SolverSpec(backend="rgb",
                                                       tile=32))
-    # mesh sharding (default) owns padding: any positive b_pad is
-    # legal; the legacy pmap path still needs whole equal shards
+    # the mesh planner owns padding: any positive b_pad is legal
     ExecSpec(bucket_m=128, b_pad=33,
              solver=SolverSpec(backend="rgb", tile=32))
     with pytest.raises(ValueError):
-        ExecSpec(bucket_m=128, b_pad=33,
-                 solver=SolverSpec(backend="rgb", tile=32),
-                 sharding="pmap")
-    with pytest.raises(ValueError):
-        ExecSpec(bucket_m=128, b_pad=32,
-                 solver=SolverSpec(backend="rgb", tile=32),
-                 sharding="banana")
+        ExecSpec(bucket_m=128, b_pad=0,
+                 solver=SolverSpec(backend="rgb", tile=32))
     # b_pad padding needs a concrete tile (tile=None means "pick per
     # shape" on every backend now — the scheduler pins it per bucket
     # via resolve_for_shape before building an ExecSpec)
@@ -122,15 +118,16 @@ def test_scheduler_accepts_spec_and_rejects_mixed_kwargs():
     spec = SolverSpec(backend="rgb", tile=8, chunk=64)
     sched = BatchScheduler(spec, max_batch=4)
     assert sched.spec.tile == 8 and sched.spec.chunk == 64
-    with pytest.raises(TypeError):
-        BatchScheduler(spec, method="rgb")
+    assert sched.batch_unit == 8
     with pytest.raises(TypeError):
         BatchScheduler("rgb")
     # tile=None stays unset on the spec (pinned per bucket at flush
-    # time); the legacy .tile view reports the serving default
+    # time); the fallback padding unit is the serving default
     sched_default = BatchScheduler(SolverSpec(backend="rgb"))
     assert sched_default.spec.tile is None
-    assert sched_default.tile == 32
+    assert sched_default.batch_unit == 32
+    # no spec: the rgb default
+    assert BatchScheduler().spec == sched_default.spec
     # shuffle specs are rejected: the flush-wide shuffle would make a
     # request's result depend on its position in the super-batch
     with pytest.raises(ValueError, match="shuffle"):
@@ -180,7 +177,7 @@ def test_pack_constraints_bucketed():
 # -- flush policies ------------------------------------------------------
 
 def test_size_triggered_flush():
-    sched = BatchScheduler(max_batch=4, tile=8)
+    sched = BatchScheduler(RGB8, max_batch=4)
     reqs = _mixed_requests(ms=(9, 10, 11, 12), reps=1)  # one bucket (16)
     futs = [sched.submit(*r) for r in reqs]
     # 4th submit hit max_batch: dispatched inline, no flush()/thread
@@ -191,19 +188,8 @@ def test_size_triggered_flush():
     assert sched.metrics.flush_reasons == {"size": 1}
 
 
-def test_size_triggered_flush_sync_mode():
-    """pipeline=False restores the stop-and-go contract: a size-
-    triggered flush completes before submit returns."""
-    sched = BatchScheduler(max_batch=4, tile=8, pipeline=False)
-    futs = [sched.submit(*r) for r in
-            _mixed_requests(ms=(9, 10, 11, 12), reps=1)]
-    assert all(f.done() for f in futs)
-    assert sched.metrics.flush_reasons == {"size": 1}
-    assert sched.metrics.inflight_now == 0
-
-
 def test_wait_triggered_flush():
-    with BatchScheduler(max_batch=1000, max_wait_s=0.02, tile=8) as sched:
+    with BatchScheduler(RGB8, max_batch=1000, max_wait_s=0.02) as sched:
         futs = [sched.submit(*r) for r in
                 _mixed_requests(ms=(5, 200), reps=1)]
         deadline = time.time() + 5.0
@@ -214,7 +200,7 @@ def test_wait_triggered_flush():
 
 
 def test_manual_flush_and_pending():
-    sched = BatchScheduler(max_batch=1000, tile=8)
+    sched = BatchScheduler(RGB8, max_batch=1000)
     futs = [sched.submit(*r) for r in _mixed_requests(reps=1)]
     assert sched.pending() == len(futs)
     n = sched.flush()
@@ -232,8 +218,9 @@ def test_flush_does_zero_repacks(method, interpret):
     """The serving hot path assembles flushes directly in the packed SoA
     layout: no AoS -> SoA conversion (core.packed.pack) may run during
     submit, flush, or result scatter — on any backend."""
-    sched = BatchScheduler(method=method, max_batch=1000, tile=8,
-                           interpret=interpret)
+    sched = BatchScheduler(SolverSpec(backend=method, tile=8,
+                                      interpret=interpret),
+                           max_batch=1000)
     reqs = _mixed_requests(reps=2)
     n0 = pack_call_count()
     futs = [sched.submit(*r) for r in reqs]
@@ -253,7 +240,7 @@ def test_flush_buffers_reused_for_stable_bucket():
     """Steady traffic on a stable bucket must not reallocate the host
     flush buffers: the per-bucket pool allocates once and every later
     flush of that shape leases the same buffers back."""
-    sched = BatchScheduler(method="rgb", max_batch=1000, tile=8)
+    sched = BatchScheduler(RGB8, max_batch=1000)
     reqs = _mixed_requests(ms=(9, 10, 11, 12), reps=1)  # one bucket (16)
     results = []
     for round_ in range(4):
@@ -355,8 +342,8 @@ def test_roundtrip_bit_identical_rgb():
 
 
 def test_roundtrip_kernel_interpret():
-    sched = BatchScheduler(method="kernel", max_batch=1000, tile=32,
-                           interpret=True)
+    sched = BatchScheduler(SolverSpec(backend="kernel", tile=32,
+                                      interpret=True), max_batch=1000)
     reqs = _mixed_requests(ms=(5, 40), reps=2)
     futs = [sched.submit(*r) for r in reqs]
     sched.flush()
@@ -370,7 +357,7 @@ def test_roundtrip_kernel_interpret():
 
 
 def test_infeasible_and_degenerate_roundtrip():
-    sched = BatchScheduler(max_batch=1000, tile=8)
+    sched = BatchScheduler(RGB8, max_batch=1000)
     rng = np.random.default_rng(7)
     theta = rng.uniform(0, 2 * np.pi, 6)
     A = np.stack([np.cos(theta), np.sin(theta)], -1).astype(np.float32)
@@ -392,7 +379,7 @@ def test_infeasible_and_degenerate_roundtrip():
 # -- executable cache ----------------------------------------------------
 
 def test_cache_hit_accounting():
-    sched = BatchScheduler(max_batch=8, tile=8)
+    sched = BatchScheduler(RGB8, max_batch=8)
     # all in one m-bucket (16) so each round is exactly one flush
     reqs = _mixed_requests(ms=(9, 10, 11, 12, 13, 14, 15, 16), reps=1)
     for round_ in range(3):
@@ -414,11 +401,10 @@ def test_cache_hit_accounting():
 
 def test_bogus_method_rejected_at_construction():
     """Stringly-typed dispatch used to fail only at flush time; the
-    SolverSpec validates when the scheduler is built."""
+    SolverSpec validates before any scheduler is built."""
     with pytest.raises(ValueError):
-        BatchScheduler(method="bogus", max_batch=1000, tile=8)
-    with pytest.raises(ValueError):
-        SolverSpec(backend="bogus")
+        BatchScheduler(SolverSpec(backend="bogus", tile=8),
+                       max_batch=1000)
 
 
 def _failing_builder(spec):
@@ -426,7 +412,7 @@ def _failing_builder(spec):
 
 
 def test_solver_error_propagates_to_futures():
-    sched = BatchScheduler(max_batch=1000, tile=8)
+    sched = BatchScheduler(RGB8, max_batch=1000)
     sched.cache = ExecutableCache(_failing_builder)
     f = sched.submit(*_mixed_requests(ms=(5,), reps=1)[0])
     with pytest.raises(ValueError):
@@ -437,7 +423,7 @@ def test_solver_error_propagates_to_futures():
 def test_timer_thread_survives_solver_error():
     """A failing wait-triggered flush must not kill the flush thread:
     later requests still get flushed (and their futures resolved)."""
-    sched = BatchScheduler(max_batch=1000, max_wait_s=0.01, tile=8)
+    sched = BatchScheduler(RGB8, max_batch=1000, max_wait_s=0.01)
     sched.cache = ExecutableCache(_failing_builder)
     sched.start()
     try:
@@ -467,15 +453,13 @@ def _selective_failing_builder(fail_bucket_m):
     return build
 
 
-@pytest.mark.parametrize("pipeline", [True, False])
-def test_multi_bucket_flush_failure_isolated(pipeline):
+def test_multi_bucket_flush_failure_isolated():
     """One bucket's failing solve must not orphan the other buckets'
     futures: every future of the flush resolves (result or exception)
     and the first error still reaches the flush() caller.  (fuse=False:
     with fusing the same isolation holds per flush *unit* — covered by
     the fused-flush tests.)"""
-    sched = BatchScheduler(max_batch=1000, tile=8, pipeline=pipeline,
-                           fuse=False)
+    sched = BatchScheduler(RGB8, max_batch=1000, fuse=False)
     sched.cache = ExecutableCache(_selective_failing_builder(16))
     # three buckets, dict order 8 -> 16 -> 128: the failure sits in the
     # middle so both an earlier and a later bucket must survive it
@@ -490,7 +474,7 @@ def test_multi_bucket_flush_failure_isolated(pipeline):
 
 
 def test_close_refuses_new_submits_and_resolves_queued():
-    sched = BatchScheduler(max_batch=1000, tile=8)
+    sched = BatchScheduler(RGB8, max_batch=1000)
     futs = [sched.submit(*r) for r in _mixed_requests(reps=1)]
     sched.close()
     for f in futs:
@@ -509,7 +493,7 @@ def test_close_vs_submit_race_never_orphans():
     flush, so a request could enqueue with no flusher left alive."""
     req = _mixed_requests(ms=(9,), reps=1)[0]
     for _ in range(5):
-        sched = BatchScheduler(max_batch=8, max_wait_s=0.001, tile=8)
+        sched = BatchScheduler(RGB8, max_batch=8, max_wait_s=0.001)
         sched.start()
         futs, lock = [], threading.Lock()
 
@@ -632,7 +616,7 @@ def test_pipelined_solve_failure_reaches_futures_not_flush_caller():
         def complete(self, handle):
             raise RuntimeError("injected completion failure")
 
-    sched = BatchScheduler(max_batch=1000, tile=8)
+    sched = BatchScheduler(RGB8, max_batch=1000)
     sched.cache = ExecutableCache(lambda s: _FailingComplete())
     f = sched.submit(*_mixed_requests(ms=(5,), reps=1)[0])
     sched.flush()          # dispatch succeeds; no raise here
@@ -760,8 +744,8 @@ def test_plan_layout_uneven_two_groups():
 
 
 def test_plan_layout_underfull_skips_devices():
-    # an underfull flush simply doesn't use trailing devices (pmap
-    # would instead pad the batch 4x to occupy them)
+    # an underfull flush simply doesn't use trailing devices (an even
+    # split would instead pad the batch 4x to occupy them)
     lay = plan_layout(16, 16, 4)
     assert lay.shards == (16, 0, 0, 0)
     assert lay.used_devices == 1 and lay.n_launches == 1
@@ -934,27 +918,69 @@ def test_fused_flush_buffer_pool_audit():
     sched.close()
 
 
-def test_pmap_escape_hatch_roundtrip():
-    """sharding="pmap" stays green: the legacy path solves the same
-    traffic bit-identically (single local device here; CI re-runs this
-    under 4 forced host devices)."""
-    spec = SolverSpec(backend="rgb", tile=8)
-    sched = BatchScheduler(spec, max_batch=1000, sharding="pmap")
-    assert not sched.fuse   # pmap's even split predates fused units
-    assert sched.batch_unit == 8 * sched.n_devices
-    reqs = _mixed_requests(ms=(3, 8, 37, 130), reps=2)
-    futs = [sched.submit(*r) for r in reqs]
-    sched.flush()
-    for (A, b, c), f in zip(reqs, futs):
-        r = f.result(timeout=120.0)
-        d = _direct_solve(spec, A, b, c)
-        assert bool(d.feasible[0]) == r.feasible
-        np.testing.assert_array_equal(np.asarray(d.x[0]), r.x)
-    sched.drain()
-    assert sched.metrics.snapshot()["fused_flushes"] == 0
-    sched.close()
-    with pytest.raises(ValueError, match="sharding"):
-        BatchScheduler(spec, sharding="banana")
+@pytest.mark.parametrize("kwargs,exc", [
+    ({"sharding": "pmap"}, ValueError),
+    ({"sharding": "banana"}, ValueError),
+    ({"pipeline": False}, ValueError),
+    ({"method": "rgb"}, TypeError),
+    ({"tile": 8}, TypeError),
+], ids=["sharding-pmap", "sharding-banana", "pipeline-off", "method",
+        "tile"])
+def test_retired_scheduler_modes_are_refused(kwargs, exc):
+    """One serve loop, one sharding path, one way to name the solver:
+    the retired modes and the flag-bag kwargs are refused at
+    construction, and the values the benchmark passes are accepted."""
+    with pytest.raises(exc):
+        BatchScheduler(RGB8, **kwargs)
+    assert BatchScheduler(RGB8, pipeline=True, sharding="mesh").fuse
+
+
+def _queues(rows):
+    # The fuse rule reads only a bucket's request count.
+    return {bm: [None] * n for bm, n in rows.items()}
+
+
+def _shape(units):
+    return [[(bm, len(q)) for bm, q in unit] for unit in units]
+
+
+@pytest.mark.parametrize("trigger,rows,units,left", [
+    # joint fill: buckets 8/16/32 hold 8 rows together
+    ("joint", {8: 3, 16: 3, 32: 2}, [[(8, 3), (16, 3), (32, 2)]], []),
+    # a joint fill takes its whole run, even past max_batch rows
+    ("joint", {8: 5, 16: 6}, [[(8, 5), (16, 6)]], []),
+    # the m ratio splits 8/16 from 256/512; the run with more rows goes
+    ("joint", {8: 2, 16: 2, 256: 3, 512: 5},
+     [[(256, 3), (512, 5)]], [8, 16]),
+    # equal runs: the first (smallest m) goes
+    ("joint", {8: 4, 16: 4, 256: 4, 512: 4}, [[(8, 4), (16, 4)]],
+     [256, 512]),
+    # no run of two buckets: nothing fires
+    ("joint", {8: 3, 128: 6}, [], [8, 128]),
+    # a manual flush caps each unit at max_batch rows
+    ("drain", {8: 3, 16: 3, 32: 3, 64: 2},
+     [[(8, 3), (16, 3)], [(32, 3), (64, 2)]], None),
+    # a full bucket flies alone; the rest fuse
+    ("drain", {8: 8, 16: 2, 32: 1}, [[(8, 8)], [(16, 2), (32, 1)]], None),
+    # the m ratio splits a drain too
+    ("drain", {8: 1, 16: 1, 128: 1, 256: 1},
+     [[(8, 1), (16, 1)], [(128, 1), (256, 1)]], None),
+], ids=["joint-three", "joint-past-cap", "joint-ratio", "joint-tie",
+        "joint-none", "drain-cap", "drain-full-single", "drain-ratio"])
+def test_fuse_triggers_share_one_rule(trigger, rows, units, left):
+    """The joint-fill trigger (submit path) and the drain planner
+    (manual and wait flushes) group buckets by one rule: sorted by
+    m_pad, split where m_pad passes the run's first times
+    fuse_max_m_ratio; the planner also splits at max_batch rows."""
+    sched = BatchScheduler(RGB8, max_batch=8)
+    sched._queues = _queues(rows)
+    if trigger == "joint":
+        fused = sched._pop_fused_locked()
+        got = [] if fused is None else [fused]
+        assert sorted(sched._queues) == left
+    else:
+        got = sched._plan_units(list(sched._queues.items()))
+    assert _shape(got) == units
 
 
 # -- multi-device sharding (out-of-process, forced host devices) ---------
@@ -975,7 +1001,7 @@ for m in (3, 8, 40, 130) * 4:
         np.float32)
     c = np.array([1.0, 0.5], np.float32)
     reqs.append((A, b, c))
-sched = BatchScheduler(method="rgb", max_batch=1000, tile=8)
+sched = BatchScheduler(SolverSpec(backend="rgb", tile=8), max_batch=1000)
 futs = [sched.submit(*r) for r in reqs]
 sched.flush()
 for (A, b, c), f in zip(reqs, futs):
@@ -992,10 +1018,10 @@ print("sharded-ok", len(reqs))
 
 
 def test_mesh_vs_pmap_bit_identity(multidevice):
-    """The tentpole equivalence claim: over an adversarial packed batch
-    (ragged + infeasible + degenerate rows), the shard_map mesh path,
-    the legacy pmap path and a plain single-launch jit produce
-    bit-identical results on 4 devices."""
+    """The mesh path's equivalence claim: over an adversarial packed
+    batch (ragged + infeasible + degenerate rows), the shard_map mesh
+    path on 4 devices, a plain single-launch jit on one device and the
+    unsharded solve produce bit-identical results."""
     code = """
 import dataclasses
 import jax, jax.numpy as jnp, numpy as np
@@ -1019,7 +1045,7 @@ lp = concat_batches(batches)
 pb = pack(lp, m_pad=32)
 B = int(pb.L.shape[0])
 assert B == 22
-# pad to the pmap-legal rung so all three paths take identical input
+# pad to 64 rows (16 per device) so all three paths take identical input
 pad = 64 - B
 Lp = np.zeros((pad, 4, 32), np.float32); Lp[:, 2, :] = 1e9
 L = np.concatenate([np.asarray(pb.L), Lp])
@@ -1032,20 +1058,19 @@ solver = SolverSpec(backend="rgb", tile=16)
 mesh_exe = build_executable(
     ExecSpec(bucket_m=32, b_pad=64, solver=solver, n_devices=4),
     jax.devices())
-pmap_exe = build_executable(
-    ExecSpec(bucket_m=32, b_pad=64, solver=solver, sharding="pmap",
-             n_devices=4),
-    jax.devices())
+single_exe = build_executable(
+    ExecSpec(bucket_m=32, b_pad=64, solver=solver, n_devices=1),
+    jax.devices()[:1])
 assert mesh_exe.shards == (16, 16, 16, 16)
-assert pmap_exe.shards == (16, 16, 16, 16)
+assert single_exe.shards == (64,) and single_exe.n_launches == 1
 xm, fm = mesh_exe(L, c, mv)
-xp, fp = pmap_exe(L, c, mv)
+xs, fs = single_exe(L, c, mv)
 from repro.core.packed import PackedLPBatch
 ref = solve_with_spec(dataclasses.replace(solver),
                       PackedLPBatch(L=jnp.asarray(L), c=jnp.asarray(c),
                                     m_valid=jnp.asarray(mv)))
-np.testing.assert_array_equal(xm, xp)
-np.testing.assert_array_equal(fm, fp)
+np.testing.assert_array_equal(xm, xs)
+np.testing.assert_array_equal(fm, fs)
 np.testing.assert_array_equal(xm, np.asarray(ref.x))
 np.testing.assert_array_equal(fm, np.asarray(ref.feasible))
 assert fm[:B].sum() == 21   # the one infeasible row stayed infeasible

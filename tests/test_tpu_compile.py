@@ -116,7 +116,7 @@ def _served_spec(n_devices):
 
 def test_served_executable_compiles(topo):
     spec = _served_spec(1)
-    layout = plan_layout(spec.b_pad, spec.tile, 1)
+    layout = plan_layout(spec.b_pad, spec.solver.tile, 1)
     fn, placement = group_launch(_make_solve(spec), [topo.devices[0]])
     text = fn.lower(*_shapes(layout.b_pad, SERVED_M, placement)) \
         .compile().as_text()
@@ -127,7 +127,7 @@ def test_served_executable_compiles_on_four_chips(topo):
     # The scheduler's default on a 4-chip host: the flush is dealt over
     # every device in at most two shard_map launch groups.
     spec = _served_spec(4)
-    layout = plan_layout(spec.b_pad, spec.tile, 4)
+    layout = plan_layout(spec.b_pad, spec.solver.tile, 4)
     assert sum(g.n_devices for g in layout.groups) == 4
     for g in layout.groups:
         devs = list(topo.devices[g.start:g.start + g.n_devices])
