@@ -51,11 +51,15 @@ reservoir mid-update or torn dispatch/complete pairs.
 """
 from __future__ import annotations
 
+import functools
 import math
+import operator
 import threading
 import time
 import warnings
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+
+import numpy as np
 
 _MAX_LATENCIES = 200_000  # reservoir size; plenty for bench runs
 
@@ -105,13 +109,14 @@ class _Histogram:
     """
 
     __slots__ = ("bounds", "counts", "overflow", "sum", "count",
-                 "exemplars")
+                 "exemplars", "_edges")
 
     def __init__(self, bounds: Tuple[float, ...] =
                  DEFAULT_DURATION_BOUNDS):
         self.bounds = tuple(float(b) for b in bounds)
         if list(self.bounds) != sorted(set(self.bounds)):
             raise ValueError("histogram bounds must strictly increase")
+        self._edges = np.asarray(self.bounds, np.float64)
         self.counts = [0] * len(self.bounds)
         self.overflow = 0            # observations > bounds[-1] (+Inf)
         self.sum = 0.0
@@ -131,6 +136,30 @@ class _Histogram:
             self.counts[idx] += 1
         if trace_id:
             self.exemplars[idx] = (v, trace_id)
+
+    def observe_many(self, values: Sequence[float],
+                     trace_ids: Optional[Sequence[Optional[str]]] = None
+                     ) -> None:
+        """:meth:`observe` of each value in order, as array operations:
+        the same counts, the same sum (added left to right) and the
+        same exemplars."""
+        v = np.asarray(values, np.float64).ravel()
+        if v.size == 0:
+            return
+        # bucket i is the first with v <= bounds[i]; NaN sorts last,
+        # into the overflow, as in _bucket_of
+        idx = np.searchsorted(self._edges, v, side="left")
+        hits = np.bincount(idx, minlength=len(self.bounds) + 1)
+        for i in np.flatnonzero(hits[:-1]).tolist():
+            self.counts[i] += int(hits[i])
+        self.overflow += int(hits[-1])
+        vals = v.tolist()
+        self.sum = functools.reduce(operator.add, vals, self.sum)
+        self.count += len(vals)
+        if trace_ids is not None:
+            for i, val, tid in zip(idx.tolist(), vals, trace_ids):
+                if tid:
+                    self.exemplars[i] = (val, tid)
 
     def _bucket_of(self, v: float) -> Optional[int]:
         lo, hi = 0, len(self.bounds)
@@ -244,6 +273,31 @@ class ServeMetrics:
             if j < self._max_latencies:
                 self._latencies[j] = seconds
 
+    def record_latencies(
+            self, seconds: Sequence[float],
+            trace_ids: Optional[Sequence[Optional[str]]] = None) -> None:
+        """Batch form of :meth:`record_latency`, one lock hold per
+        flush: the same histogram and the same reservoir (the same
+        replacement draws, in order) as the samples sent one by one."""
+        v = np.asarray(seconds, np.float64).ravel()
+        vals = v.tolist()
+        with self._lock:
+            self.hists["request_latency_seconds"].observe_many(
+                v, trace_ids)
+            lat = self._latencies
+            k = self._max_latencies
+            head = vals[:max(0, k - len(lat))]
+            lat.extend(head)
+            self.lat_seen += len(head)
+            rng = self._lat_rng
+            for sample in vals[len(head):]:
+                self.lat_seen += 1
+                rng = (rng * _LCG_MUL + _LCG_INC) & _LCG_MASK
+                j = rng % self.lat_seen
+                if j < k:
+                    lat[j] = sample
+            self._lat_rng = rng
+
     def record_queue_wait(self, seconds: float,
                           trace_id: Optional[str] = None) -> None:
         """One request's submit -> flush-assembly wait (observed at
@@ -252,13 +306,13 @@ class ServeMetrics:
             self.hists["queue_wait_seconds"].observe(seconds, trace_id)
 
     def record_queue_waits(
-            self, waits: List[Tuple[float, Optional[str]]]) -> None:
+            self, seconds: Sequence[float],
+            trace_ids: Optional[Sequence[Optional[str]]] = None) -> None:
         """Batch form of :meth:`record_queue_wait` — one lock hold per
         flush instead of one per member request."""
         with self._lock:
-            h = self.hists["queue_wait_seconds"]
-            for seconds, trace_id in waits:
-                h.observe(seconds, trace_id)
+            self.hists["queue_wait_seconds"].observe_many(
+                seconds, trace_ids)
 
     def record_dispatch(self) -> int:
         """One flush handed to the device; returns the in-flight depth

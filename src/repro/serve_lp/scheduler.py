@@ -106,12 +106,10 @@ def _try_set_result(fut: Future, value: Any) -> bool:
     """``fut.set_result(value)``, tolerating a concurrent cancel.
 
     The RPC layer cancels futures from the asyncio thread on deadline
-    expiry while flush threads settle them; a ``done()`` pre-check only
-    narrows that window.  Losing the race must skip *one* future — an
-    ``InvalidStateError`` escaping here would abort the completion
-    scatter mid-flush and orphan every later future of the flush."""
-    if fut.done():
-        return False
+    expiry while flush threads settle them.  Losing the race must skip
+    *one* future — an ``InvalidStateError`` escaping here would abort
+    the completion scatter mid-flush and orphan every later future of
+    the flush."""
     try:
         fut.set_result(value)
         return True
@@ -122,8 +120,6 @@ def _try_set_result(fut: Future, value: Any) -> bool:
 def _try_set_exception(fut: Future, exc: BaseException) -> bool:
     """``fut.set_exception(exc)`` with the same race tolerance as
     :func:`_try_set_result`."""
-    if fut.done():
-        return False
     try:
         fut.set_exception(exc)
         return True
@@ -153,6 +149,10 @@ class _FlushBufferPool:
     :meth:`release`.  (The pre-pipelining context-manager lease that
     returned buffers in a ``finally`` right after the solve call was
     only sound while executables were synchronous.)
+
+    Between flushes a set is put back to the neutral background only
+    where its last flush wrote: that flush's ``mv`` bounds each row it
+    filled (:func:`_reset_written`).
     """
 
     def __init__(self, max_per_key: int = 2):
@@ -176,17 +176,13 @@ class _FlushBufferPool:
             if bufs is None:
                 self.alloc_count += 1
         if bufs is None:
-            bufs = (np.empty((b_pad, 4, bm), dtype),
-                    np.empty((b_pad, 2), dtype),
-                    np.empty((b_pad, 1), np.int32))
-        L, c, mv = bufs
-        # Reset to the neutral flush background: padding columns and
-        # problems must look exactly like freshly zeroed buffers.
-        L.fill(0.0)
-        L[:, 2, :] = PAD_B
-        c[:, 0] = 1.0
-        c[:, 1] = 0.0
-        mv.fill(0)
+            bufs = (np.zeros((b_pad, 4, bm), dtype),
+                    np.zeros((b_pad, 2), dtype),
+                    np.zeros((b_pad, 1), np.int32))
+            bufs[0][:, 2, :] = PAD_B
+            bufs[1][:, 0] = 1.0
+        else:
+            _reset_written(*bufs)
         return key, bufs
 
     def release(self, key: tuple, bufs: tuple) -> None:
@@ -196,6 +192,29 @@ class _FlushBufferPool:
             stack = self._free.setdefault(key, [])
             if len(stack) < self._max_per_key:
                 stack.append(bufs)
+
+
+def _reset_written(L: np.ndarray, c: np.ndarray, mv: np.ndarray) -> None:
+    """Put the neutral flush background (a_x = a_y = 0, b = PAD_B,
+    c = (1, 0), m_valid = 0) back over what the last flush wrote: rows
+    ``0 .. n-1``, each up to its ``m_valid``.  The rows are cleared in
+    a few rectangles, each as wide as the widest row up to it rounded
+    up to a power of two (fused flushes hold their buckets in rising
+    m, so a flush of m 8..1024 clears mostly narrow rows)."""
+    written = np.flatnonzero(mv[:, 0])
+    if written.size == 0:
+        return
+    n = int(written[-1]) + 1
+    reach = np.maximum.accumulate(mv[:n, 0])
+    width = np.minimum(np.exp2(np.ceil(np.log2(reach))).astype(np.int64),
+                       L.shape[2])
+    starts = [0] + (np.flatnonzero(np.diff(width)) + 1).tolist()
+    for s, e in zip(starts, starts[1:] + [n]):
+        w = int(width[s])
+        L[s:e, :2, :w] = 0.0
+        L[s:e, 2, :w] = PAD_B
+    c[:n] = (1.0, 0.0)
+    mv[:n] = 0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -211,13 +230,12 @@ class LPResult:
     latency_s: float     # submit -> result
 
 
-@dataclasses.dataclass
+@dataclasses.dataclass(slots=True)
 class _Pending:
-    """One queued request, already split into the packed row layout so
-    a flush copies straight into the ``L`` block."""
+    """One queued request at the spec's dtype; a flush copies its
+    columns straight into the ``L`` block."""
 
-    ax: np.ndarray       # (m,) constraint normal x-components
-    ay: np.ndarray       # (m,) constraint normal y-components
+    A: np.ndarray        # (m, 2) constraint normals, the request's own copy
     b: np.ndarray        # (m,) offsets
     c: np.ndarray        # (2,) objective
     m: int
@@ -250,6 +268,8 @@ class _InflightFlush:
     buf_key: tuple               # pool lease (returned at completion)
     bufs: tuple                  # (L, c, mv) host arrays
     t_assemble: float            # assembly start
+    t_submit: Any = None         # (B,) each request's submit time
+    sum_m: int = 0               # valid constraint rows of the flush
     n_buckets: int = 1           # m-buckets fused into this unit
     t_dispatch: float = 0.0      # dispatch enqueued (device handed work)
     t_complete: float = 0.0      # device results materialized on host
@@ -571,8 +591,8 @@ class BatchScheduler:
     def submit(self, A, b, c, *,
                trace: Optional[TraceContext] = None) -> Future:
         """Submit one LP (A (m,2), b (m,), c (2,)); returns a Future
-        resolving to :class:`LPResult`.  Buffers are kept at the spec's
-        dtype and pre-split into packed rows.
+        resolving to :class:`LPResult`.  ``A`` is copied once, at the
+        spec's dtype, so the caller may reuse it once this returns.
 
         ``trace`` propagates an upstream :class:`TraceContext` (the RPC
         layer's parsed ``X-Trace-Id``); when the scheduler's tracer is
@@ -580,16 +600,14 @@ class BatchScheduler:
         here, so every traced request has a full span chain either
         way."""
         dt = self._dtype
-        A = np.asarray(A, dt).reshape(-1, 2)
+        A = np.array(A, dt).reshape(-1, 2)
         m = A.shape[0]
         b = np.asarray(b, dt).reshape(m)
         c = np.asarray(c, dt).reshape(2)
         if m < 1:
             raise ValueError("LP needs at least one constraint")
         fut: Future = Future()
-        req = _Pending(ax=np.ascontiguousarray(A[:, 0]),
-                       ay=np.ascontiguousarray(A[:, 1]),
-                       b=b, c=c, m=m, future=fut,
+        req = _Pending(A=A, b=b, c=c, m=m, future=fut,
                        t_submit=time.perf_counter())
         bm = bucket_m(m, base=self.bucket_base)
         tracer = self.tracer
@@ -645,7 +663,7 @@ class BatchScheduler:
         ``max_batch`` rows.  The run is not capped at ``max_batch``: a
         joint fill pops every bucket of its run.
         """
-        total = sum(len(q) for q in self._queues.values())
+        total = sum(map(len, self._queues.values()))
         if total < self.max_batch:
             return None
         cands = [(b, q) for b, q in self._queues.items()
@@ -877,14 +895,14 @@ class BatchScheduler:
         tracer = self.tracer
         live: List[Tuple[int, List[_Pending]]] = []
         for bm_i, q in parts:
-            kept: List[_Pending] = []
-            for r in q:
-                if r.future.set_running_or_notify_cancel():
-                    kept.append(r)
-                else:
-                    tracer.end(r.qspan, cancelled=True)
-                    tracer.end(r.span, cancelled=True)
-                    r.qspan = r.span = None
+            kept = [r for r in q
+                    if r.future.set_running_or_notify_cancel()]
+            if tracer.enabled and len(kept) < len(q):
+                for r in q:
+                    if r.future.cancelled():
+                        tracer.end(r.qspan, cancelled=True)
+                        tracer.end(r.span, cancelled=True)
+                        r.qspan = r.span = None
             if kept:
                 live.append((bm_i, kept))
         if not live:
@@ -899,8 +917,12 @@ class BatchScheduler:
             with self._inflight_cv:
                 self._active += 1
         try:
-            unit = self._assemble(bm, reqs, reason,
-                                  n_buckets=len(live))
+            # One profiler span per flush around the host-side assembly;
+            # dispatch's backpressure wait lies outside it.
+            with jax.profiler.TraceAnnotation(
+                    "serve.assemble", bucket_m=bm, rows=len(reqs)):
+                unit = self._assemble(bm, reqs, reason,
+                                      n_buckets=len(live))
             self._dispatch(unit)
         except Exception as e:  # propagate to every waiter, don't hang
             with self._inflight_cv:
@@ -908,9 +930,10 @@ class BatchScheduler:
                 self._inflight_cv.notify_all()
             for r in reqs:
                 _try_set_exception(r.future, e)
-                tracer.end(r.qspan, error=type(e).__name__)
-                tracer.end(r.span, error=type(e).__name__)
-                r.qspan = r.span = None
+                if tracer.enabled:
+                    tracer.end(r.qspan, error=type(e).__name__)
+                    tracer.end(r.span, error=type(e).__name__)
+                    r.qspan = r.span = None
             raise
 
     def _assemble(self, bm: int, reqs: List[_Pending],
@@ -918,7 +941,9 @@ class BatchScheduler:
         """Host-side stage: lease packed buffers, fill them directly in
         the SoA layout (neutral columns/problems are a_x = a_y = 0,
         b = PAD_B, c = (1, 0), m_valid = 0 — no AoS intermediate, no
-        device-side re-stack) and resolve the executable."""
+        device-side re-stack) and resolve the executable.  ``m_valid``,
+        ``c`` and the queue waits are written for the whole flush at
+        once; each request then costs two row copies."""
         B = len(reqs)
         pinned, b_pad = self.flush_shape(bm, B)
         spec = ExecSpec(bucket_m=bm, b_pad=b_pad, solver=pinned,
@@ -949,19 +974,19 @@ class BatchScheduler:
             for r in reqs:
                 tracer.end(r.qspan, t_end=t0, flush=name)
                 r.qspan = None
-        self.metrics.record_queue_waits(
-            [(t0 - r.t_submit,
-              r.trace.trace_id if r.trace is not None else None)
-             for r in reqs])
+        t_submit = np.array([r.t_submit for r in reqs])
+        self.metrics.record_queue_waits(t0 - t_submit,
+                                        self._trace_ids(reqs))
         key, bufs = self.buffers.lease(b_pad, bm, self._dtype)
         try:
             L, c, mv = bufs
+            # m_valid first: it bounds what the pool resets next lease
+            mv[:B, 0] = [r.m for r in reqs]
+            c[:B] = [r.c for r in reqs]
             for i, r in enumerate(reqs):
-                L[i, 0, :r.m] = r.ax
-                L[i, 1, :r.m] = r.ay
+                L[i, :2, :r.m] = r.A.T
                 L[i, 2, :r.m] = r.b
-                c[i] = r.c
-                mv[i, 0] = r.m
+            sum_m = int(mv[:B, 0].sum())
             exe = as_executable(self.cache.get(spec))
         except Exception:
             self.buffers.release(key, bufs)
@@ -970,7 +995,8 @@ class BatchScheduler:
         return _InflightFlush(
             name=name, bucket_m=bm, b_pad=b_pad,
             reqs=reqs, reason=reason, exe=exe, buf_key=key, bufs=bufs,
-            t_assemble=t0, n_buckets=n_buckets,
+            t_assemble=t0, t_submit=t_submit, sum_m=sum_m,
+            n_buckets=n_buckets,
             trace_id=trace_id, asm_span=asm_span)
 
     def _dispatch(self, unit: _InflightFlush) -> None:
@@ -1048,10 +1074,9 @@ class BatchScheduler:
                     warn=f"serve_lp: completion worker error {e!r}")
 
     def _complete_unit(self, unit: _InflightFlush) -> None:
-        """Join stage: block on the device results, return the leased
-        buffers (safe only now — see :class:`_FlushBufferPool`), record
-        metrics, scatter futures.  A solve error is counted as a
-        ``"solve"`` error and lands on the unit's futures."""
+        """Join stage: block on the device results, then settle the
+        flush under one ``serve.settle`` profiler span: from the
+        results on the host to the last future settled."""
         err: Optional[BaseException] = None
         x = feas = None
         try:
@@ -1060,6 +1085,32 @@ class BatchScheduler:
             err = e
         unit.t_complete = time.perf_counter()
         unit.handle = None
+        with jax.profiler.TraceAnnotation(
+                "serve.settle", flush=unit.name, bucket_m=unit.bucket_m,
+                rows=len(unit.reqs)):
+            self._settle(unit, x, feas, err)
+        unit.done.set()
+
+    def _trace_ids(self, reqs: List[_Pending]
+                   ) -> Optional[List[Optional[str]]]:
+        """Each request's trace id; None while the tracer is off (no
+        request then has one)."""
+        if not self.tracer.enabled:
+            return None
+        return [r.trace.trace_id if r.trace is not None else None
+                for r in reqs]
+
+    def _settle(self, unit: _InflightFlush, x, feas,
+                err: Optional[BaseException]) -> None:
+        """Return the leased buffers (safe only now — see
+        :class:`_FlushBufferPool`), record metrics and settle every
+        future in submission order, the flush's columns computed at
+        once.  A solve error is counted as a ``"solve"`` error and
+        lands on the unit's futures."""
+        reqs = unit.reqs
+        B = len(reqs)
+        # The flush's objectives, kept past the release of its buffers.
+        c = unit.bufs[1][:B].copy()
         # Device is synchronized (or dead): the host buffers are free.
         self.buffers.release(unit.buf_key, unit.bufs)
         with self._inflight_cv:
@@ -1086,37 +1137,31 @@ class BatchScheduler:
             # recorder (via the record_error hook) so its snapshot holds
             # them as evidence, and only then settle the futures — a
             # caller woken by its future sees evidence fully captured.
-            for r in unit.reqs:
-                tracer.end(r.span, error=type(err).__name__,
-                           flush=unit.name)
-                r.span = None
+            if tracer.enabled:
+                for r in reqs:
+                    tracer.end(r.span, error=type(err).__name__,
+                               flush=unit.name)
+                    r.span = None
             tracer.end(sspan, error=type(err).__name__)
             self.metrics.record_error(
                 "solve",
                 warn=f"serve_lp: {unit.name} failed ({err!r}); its "
                      "futures carry the exception")
-            for r in unit.reqs:
+            for r in reqs:
                 _try_set_exception(r.future, err)
-            unit.done.set()
             return
-        B = len(unit.reqs)
         now = time.perf_counter()
+        latency = now - unit.t_submit
         # Metrics before the scatter: a caller woken by future.result()
         # observes a fully consistent snapshot (flush counted, buffers
-        # back in the pool, in-flight gauge decremented).  The flush's
-        # futures were claimed in _solve, so a concurrent cancel can no
-        # longer settle them — and the scatter below tolerates a lost
-        # settle race anyway rather than orphaning the rest of the
-        # flush.
-        for r in unit.reqs:
-            if not r.future.done():
-                self.metrics.record_latency(
-                    now - r.t_submit,
-                    trace_id=(r.trace.trace_id
-                              if r.trace is not None else None))
+        # back in the pool, in-flight gauge decremented).  Every member
+        # was claimed in _solve_unit, so no cancel can settle it first;
+        # the scatter still tolerates a lost settle race rather than
+        # orphaning the rest of the flush.
+        self.metrics.record_latencies(latency, self._trace_ids(reqs))
         self.metrics.record_flush(
             n_real=B, b_pad=unit.b_pad, bucket_m=unit.bucket_m,
-            sum_m=sum(r.m for r in unit.reqs),
+            sum_m=unit.sum_m,
             solve_seconds=unit.t_complete - unit.t_dispatch,
             assemble_seconds=unit.t_dispatch - unit.t_assemble,
             reason=unit.reason,
@@ -1127,27 +1172,26 @@ class BatchScheduler:
         if self.recorder is not None:
             self.recorder.maybe_check_p99(
                 lambda: self.metrics.percentile(99.0))
-        for i, r in enumerate(unit.reqs):
-            if r.future.done():
-                tracer.end(r.span, t_end=now, flush=unit.name,
-                           dropped=True)
+        x = np.asarray(x)[:B]
+        objective = c[:, 0] * x[:, 0] + c[:, 1] * x[:, 1]
+        feasible = np.asarray(feas)[:B].astype(bool).tolist()
+        if tracer.enabled:
+            for r, f in zip(reqs, feasible):
+                if r.future.done():
+                    tracer.end(r.span, t_end=now, flush=unit.name,
+                               dropped=True)
+                else:
+                    tracer.end(r.span, t_end=now, flush=unit.name,
+                               feasible=f)
                 r.span = None
-                continue
-            xi = np.asarray(x[i])
+        bm = unit.bucket_m
+        for r, xi, f, obj, lat in zip(reqs, x, feasible,
+                                      objective.tolist(),
+                                      latency.tolist()):
             _try_set_result(r.future, LPResult(
-                x=xi,
-                feasible=bool(feas[i]),
-                objective=float(r.c @ xi),
-                m=r.m,
-                bucket_m=unit.bucket_m,
-                batch_size=B,
-                latency_s=now - r.t_submit,
-            ))
-            tracer.end(r.span, t_end=now, flush=unit.name,
-                       feasible=bool(feas[i]))
-            r.span = None
+                x=xi, feasible=f, objective=obj, m=r.m, bucket_m=bm,
+                batch_size=B, latency_s=lat))
         tracer.end(sspan)
-        unit.done.set()
 
     def _record_device_spans(self, unit: _InflightFlush,
                              parent: Optional[str]) -> None:

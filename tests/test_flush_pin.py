@@ -192,7 +192,7 @@ for bm, B in [(128, 5), (128, 17), (128, 64), (128, 100), (1024, 200),
     est = SLOController(10.0, table=table,
                         device_kind=kind).plan_for(sched, bm).est_flush_s
     m = bm // 2 + 1
-    reqs = [_Pending(ax=np.ones(m, np.float32), ay=np.zeros(m, np.float32),
+    reqs = [_Pending(A=np.tile(np.float32([1.0, 0.0]), (m, 1)),
                      b=np.ones(m, np.float32),
                      c=np.array([1.0, 0.0], np.float32), m=m,
                      future=Future(), t_submit=time.perf_counter())

@@ -4,6 +4,7 @@ isolation), executable cache accounting, round-trip equivalence with
 the direct solvers, and multi-device sharding (out-of-process)."""
 import threading
 import time
+from concurrent.futures import Future
 
 import jax
 import numpy as np
@@ -18,8 +19,11 @@ from repro.serve_lp import (BatchScheduler, ExecSpec, ExecutableCache,
                             SolverSpec, as_executable, bucket_batch,
                             bucket_m, build_executable, plan_layout,
                             shape_ladder)
+from repro.core.lp import PAD_B
+from repro.obs import Tracer
 from repro.serve_lp.bench import BenchConfig, make_request, run_traffic
-from repro.serve_lp.scheduler import _FlushBufferPool
+from repro.serve_lp.metrics import DEFAULT_DURATION_BOUNDS
+from repro.serve_lp.scheduler import _FlushBufferPool, _Pending
 
 RGB8 = SolverSpec(backend="rgb", tile=8)
 
@@ -266,6 +270,46 @@ def test_flush_buffers_reused_for_stable_bucket():
     assert sched.buffers.alloc_count == 2
 
 
+def _pending(ms, seed):
+    """Queued requests of constraint counts ``ms``, as submit makes them."""
+    return [_Pending(A=np.array(A, np.float32), b=b, c=c, m=len(b),
+                     future=Future(), t_submit=time.perf_counter())
+            for A, b, c in _mixed_requests(seed=seed, ms=ms, reps=1)]
+
+
+@pytest.mark.parametrize("first_ms", [
+    (64, 60, 63, 33, 64, 50, 64, 41),      # one bucket, m in any order
+    (3, 9, 12, 20, 33, 40, 60, 64),        # a fused flush, rising m
+    (64, 50, 33, 20, 12, 9, 3, 1),         # falling m
+], ids=["one_bucket", "rising", "falling"])
+def test_reused_flush_buffers_hold_the_neutral_background(first_ms):
+    """A buffer set reused by a flush with smaller m and fewer rows than
+    its last one holds exactly the neutral background (a = 0, b =
+    PAD_B, c = (1, 0), m_valid = 0, bit for bit) beyond each row's m
+    and beyond the flush's rows, and no new set is allocated."""
+    sched = BatchScheduler(RGB8, max_batch=1000)
+    first = sched._assemble(64, _pending(first_ms, 1), "manual")
+    sched.buffers.release(first.buf_key, first.bufs)
+    small = _pending((5, 40, 9), 2)
+    unit = sched._assemble(64, small, "manual")
+    assert unit.buf_key == first.buf_key
+    assert unit.bufs[0] is first.bufs[0]
+    assert sched.buffers.alloc_count == 1
+    L, c, mv = unit.bufs
+    want_L = np.zeros_like(L)
+    want_L[:, 2, :] = PAD_B
+    want_c = np.zeros_like(c)
+    want_c[:, 0] = 1.0
+    want_mv = np.zeros_like(mv)
+    for i, r in enumerate(small):
+        want_L[i, :2, :r.m] = r.A.T
+        want_L[i, 2, :r.m] = r.b
+        want_c[i] = r.c
+        want_mv[i] = r.m
+    for got, want in ((L, want_L), (c, want_c), (mv, want_mv)):
+        assert got.tobytes() == want.tobytes()
+
+
 def test_scheduler_pins_tuned_config_per_bucket():
     """A tuning-table entry matching a bucket's shape class changes the
     launch geometry of that bucket's executable; a miss keeps the
@@ -305,7 +349,7 @@ def test_submit_honors_spec_dtype():
     req = _mixed_requests(ms=(5,), reps=1)[0]
     sched.submit(np.asarray(req[0], np.float64), req[1], req[2])
     q = next(iter(sched._queues.values()))
-    assert q[0].ax.dtype == np.float32 and q[0].b.dtype == np.float32
+    assert q[0].A.dtype == np.float32 and q[0].b.dtype == np.float32
     assert q[0].c.dtype == np.float32
     sched.flush()
     if jax.config.jax_enable_x64:
@@ -313,7 +357,7 @@ def test_submit_honors_spec_dtype():
                                         dtype="float64"))
         s64.submit(*req)
         q = next(iter(s64._queues.values()))
-        assert q[0].ax.dtype == np.float64
+        assert q[0].A.dtype == np.float64
         s64.flush()
     else:
         # x64 off: a float64 spec is rejected at construction, exactly
@@ -374,6 +418,96 @@ def test_infeasible_and_degenerate_roundtrip():
     np.testing.assert_allclose(fd.result().x, xstar, rtol=1e-4,
                                atol=1e-4)
     assert not fi.result().feasible
+
+
+# -- bulk settle: every result against a per-request reference -----------
+
+class _RecordingExec:
+    """Executable double: answers a flush with seeded ``x`` rows (wide
+    magnitudes, signed zeros) and flags, and records what it was given
+    and what it answered."""
+
+    def __init__(self, log):
+        self.log = log
+
+    def dispatch(self, L, c, mv):
+        rng = np.random.default_rng(len(self.log))
+        n = L.shape[0]
+        x = (rng.standard_normal((n, 2))
+             * 10.0 ** rng.uniform(-4, 4, (n, 2))).astype(L.dtype)
+        x[::5, 0] = -0.0
+        feas = rng.random(n) < 0.7
+        self.log.append((L.copy(), x, feas))
+        return x, feas
+
+    def complete(self, handle):
+        return handle
+
+
+# constraint counts of one flush each, and the futures cancelled while
+# queued; fused runs fuse whole (fuse_max_m_ratio below)
+_FLUSH_SHAPES = {
+    "one_bucket": ((9, 10, 11, 12, 13, 14, 15, 16), ()),
+    "fused_8_to_1024": ((8, 16, 32, 64, 128, 256, 512, 1024) * 2, ()),
+    "fused_2048_4096": ((2048, 4096, 3000, 2500), ()),
+    "one_request": ((37,), ()),
+    "cancelled_while_queued": ((9, 10, 11, 12, 13, 14), (1, 4)),
+}
+
+
+@pytest.mark.parametrize("traced", [False, True],
+                         ids=["untraced", "traced"])
+@pytest.mark.parametrize("shape", sorted(_FLUSH_SHAPES))
+def test_bulk_settle_matches_per_request_reference(shape, traced):
+    """Every settled LPResult equals the one built request by request
+    from the flush's own ``x``/``feasible`` arrays: ``x`` (bit for bit),
+    ``feasible``, ``m``, ``bucket_m`` and ``batch_size`` equal, and
+    ``objective`` within one float32 ulp of ``float(c @ x)``."""
+    ms, cancel = _FLUSH_SHAPES[shape]
+    log = []
+    tracer = Tracer(enabled=True) if traced else None
+    sched = BatchScheduler(RGB8, max_batch=1000, fuse_max_m_ratio=1024.0,
+                           tracer=tracer)
+    sched.cache = ExecutableCache(lambda s: _RecordingExec(log))
+    reqs = _mixed_requests(seed=len(ms), ms=ms, reps=1)
+    futs = [sched.submit(*r) for r in reqs]
+    for i in cancel:
+        assert futs[i].cancel()
+    sched.flush()
+    assert sched.drain(timeout=60.0)
+    assert len(log) == 1
+    L, x, feas = log[0]
+    # a fused flush holds its buckets in rising m, each in submission
+    # order
+    live = sorted((i for i in range(len(reqs)) if i not in cancel),
+                  key=lambda i: bucket_m(len(reqs[i][1]), base=8))
+    for row, i in enumerate(live):
+        A, b, c = reqs[i]
+        m = len(b)
+        # the flush's row ``row`` holds request i
+        np.testing.assert_array_equal(L[row, :2, :m], A.T)
+        np.testing.assert_array_equal(L[row, 2, :m], b)
+        got = futs[i].result(timeout=10.0)
+        assert got.x.tobytes() == x[row].tobytes()
+        assert type(got.feasible) is bool
+        assert got.feasible == bool(feas[row])
+        assert (got.m, got.bucket_m, got.batch_size) == \
+            (m, L.shape[2], len(live))
+        want = float(np.asarray(c, np.float32) @ x[row])
+        assert type(got.objective) is float
+        np.testing.assert_array_max_ulp(np.float32(got.objective),
+                                        np.float32(want), maxulp=1)
+        assert got.latency_s >= 0.0
+    assert all(futs[i].cancelled() for i in cancel)
+    if traced:
+        ends = {s.attrs.get("feasible") for s in tracer.spans()
+                if s.name == "request" and "feasible" in s.attrs}
+        assert ends <= {True, False} and ends
+        n_cancelled = sum(1 for s in tracer.spans() if s.name == "request"
+                          and s.attrs.get("cancelled"))
+        assert n_cancelled == len(cancel)
+    else:
+        assert sched.tracer.stats()["spans_started"] == 0
 
 
 # -- executable cache ----------------------------------------------------
@@ -678,6 +812,36 @@ def test_latency_reservoir_stays_uniform():
     assert m2._latencies == m._latencies
     # the report names the sampling so percentiles aren't over-read
     assert "reservoir: 100 of 5000" in m.format_report()
+
+
+@pytest.mark.parametrize("family", ["request_latency_seconds",
+                                    "queue_wait_seconds"])
+def test_batch_recording_matches_one_by_one(family):
+    """The batch forms (``record_latencies``, ``record_queue_waits``)
+    give the same histogram counts, sums, exemplars and, for latencies,
+    the same reservoir as the samples sent one by one — past the
+    reservoir's capacity too."""
+    rng = np.random.default_rng(11)
+    vals = np.concatenate([10.0 ** rng.uniform(-5, 3, 400),
+                           DEFAULT_DURATION_BOUNDS[::4]])  # on bounds
+    rng.shuffle(vals)
+    tids = [f"{i:032x}" if i % 3 == 0 else None for i in range(len(vals))]
+    one = ServeMetrics(max_latency_samples=64)
+    bulk = ServeMetrics(max_latency_samples=64)
+    if family == "request_latency_seconds":
+        single, batch = one.record_latency, bulk.record_latencies
+    else:
+        single, batch = one.record_queue_wait, bulk.record_queue_waits
+    for v, t in zip(vals.tolist(), tids):
+        single(v, trace_id=t)
+    cuts = [0, 1, 9, 60, 61, 200, len(vals)]
+    for s, e in zip(cuts, cuts[1:]):
+        batch(vals[s:e], tids[s:e])
+    assert bulk.hists[family].state() == one.hists[family].state()
+    assert bulk.hists[family].count == len(vals)
+    assert bulk.lat_seen == one.lat_seen
+    assert bulk._latencies == one._latencies
+    assert bulk._lat_rng == one._lat_rng
 
 
 def test_error_counter_and_one_time_warning():
